@@ -140,6 +140,8 @@ def main(argv=None) -> None:
                          "(dump under results/profile) and activate "
                          "kernel-site trace annotations")
     args = ap.parse_args(argv)
+    from repro.platform import enable_compile_cache
+    enable_compile_cache()
     if args.gate:
         sys.exit(run_gate(args.gate_threshold))
     only = [s for s in args.only.split(",") if s]

@@ -17,6 +17,7 @@ exercise spec validation and the registry on every run.
 
 import argparse
 import json
+import os
 import sys
 
 
@@ -42,6 +43,14 @@ def main(argv=None):
     ap.add_argument("--port", type=int, default=None,
                     help="socket mode: override spec.transport.port")
     args = ap.parse_args(argv)
+
+    if args.role == "device":
+        # the device role models an edge device: pin it to the host CPU
+        # before JAX initialises, leaving the accelerator to the server
+        # role's process (a chip belongs to one process at a time)
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from repro.platform import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs.base import replace
     from repro.experiments import ExperimentSpec, run_experiment
@@ -94,7 +103,6 @@ def main(argv=None):
         return 0
 
     if args.profile:
-        import os
         from repro.observability.profiling import profile_run
         logdir = os.path.join(
             spec.results_dir or f"results/{spec.name}", "profile")

@@ -1,9 +1,10 @@
 """Architecture registry: ``--arch <id>`` resolution.
 
 ``get_config(name)`` returns the full published config; ``get_smoke_config``
-returns the reduced same-family config used by CPU smoke tests.  The full
-configs are only ever instantiated abstractly (ShapeDtypeStruct) by the
-dry-run; smoke configs are the ones that allocate real arrays.
+returns the reduced same-family config used by CPU smoke tests.  Full
+configs of the paper's models (mobilenet-l, vit-s) train on a TPU through
+``run_experiment`` (``chip_smoke.py``); the dry-run and the TPU compile
+tests lower full configs from shapes without allocating them.
 """
 
 from __future__ import annotations

@@ -734,9 +734,12 @@ class AmpereTrainer:
         epochs = max_epochs if max_epochs is not None else run.fed.server_epochs
 
         bs = run.fed.server_batch_size
+        if store.num_samples() < bs:
+            raise ValueError(
+                f"server batch {bs} exceeds the consolidated pool of "
+                f"{store.num_samples()} samples: no server step would run")
         budget = run.device_pool_budget_mb * 2 ** 20
-        resident = (store.num_samples() >= bs
-                    and store.pool_nbytes() <= budget)
+        resident = store.pool_nbytes() <= budget
         pool_dev = None
         if resident:
             pool_dev = {k: jnp.asarray(v)

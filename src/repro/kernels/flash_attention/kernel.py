@@ -31,7 +31,11 @@ across revisits (no flush/refetch), so that case accumulates in VMEM
 scratch instead.
 
 Layouts:  q, o: (BH, S, hd) with BH = B * Hkv * G (kv-major: bh // G is the
-kv head); k, v: (BKV, Skv, hd) with BKV = B * Hkv.
+kv head); k, v: (BKV, Skv, hd) with BKV = B * Hkv; the per-row statistics
+lse and delta: (BH, 1, S), the sequence on lanes, so their (1, 1, bq)
+blocks meet the TPU's (8, 128) tiling (a (1, bq) block over (BH, S) is
+refused by the compiler).  Kernels transpose a (1, bq) row to the (bq, 1)
+column the score tile broadcasts against.
 """
 
 from __future__ import annotations
@@ -43,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.platform import pallas_interpret
 
 NEG_INF = -1e30
 
@@ -98,20 +104,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *,
     def _final():
         l = jnp.maximum(l_sc[...], 1e-30)
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_sc[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = (m_sc[...] + jnp.log(l)).T
 
 
 def flash_fwd(q, k, v, *, group: int, causal: bool, window: int,
               softcap: float, scale: float, kv_len: int,
               block_q: int = 128, block_k: int = 128, interpret=None):
-    """q: (BH, Sq, hd); k, v: (BKV, Skv, hd).  Sq, Skv padded to blocks."""
+    """q: (BH, Sq, hd); k, v: (BKV, Skv, hd).  Sq, Skv padded to blocks.
+    Returns o (BH, Sq, hd) fp32 and lse (BH, 1, Sq)."""
     BH, Sq, hd = q.shape
     BKV, Skv = k.shape[0], k.shape[1]
     bq, bk = min(block_q, Sq), min(block_k, Skv)
     assert Sq % bq == 0 and Skv % bk == 0
     nq, nk = Sq // bq, Skv // bk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     kern = functools.partial(_fwd_kernel, causal=causal, window=window,
                              softcap=softcap, scale=scale, kv_len=kv_len,
@@ -128,11 +135,11 @@ def flash_fwd(q, k, v, *, group: int, causal: bool, window: int,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -181,8 +188,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]
-    delta = delta_ref[0][:, None]
+    lse = lse_ref[0].T                                # (bq, 1)
+    delta = delta_ref[0].T
 
     p, dchain = _recompute_p(q, k, iq, ik, bq, bk, causal=causal,
                              window=window, softcap=softcap, scale=scale,
@@ -205,7 +212,7 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, group, causal, window, softcap,
     bq, bk = min(block_q, Sq), min(block_k, Skv)
     nq, nk = Sq // bq, Skv // bk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     kern = functools.partial(_dq_kernel, causal=causal, window=window,
                              softcap=softcap, scale=scale, kv_len=kv_len,
                              nk=nk)
@@ -217,8 +224,8 @@ def flash_bwd_dq(q, k, v, do, lse, delta, *, group, causal, window, softcap,
             pl.BlockSpec((1, bk, hd), lambda bh, iq, ik, g=group: (bh // g, ik, 0)),
             pl.BlockSpec((1, bk, hd), lambda bh, iq, ik, g=group: (bh // g, ik, 0)),
             pl.BlockSpec((1, bq, hd), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
-            pl.BlockSpec((1, bq), lambda bh, iq, ik: (bh, iq)),
+            pl.BlockSpec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
+            pl.BlockSpec((1, 1, bq), lambda bh, iq, ik: (bh, 0, iq)),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, hd), jnp.float32),
@@ -261,7 +268,7 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, group, causal, window, softcap,
     bq, bk = min(block_q, Sq), min(block_k, Skv)
     nq, nk = Sq // bq, Skv // bk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     kern = functools.partial(_dkv_kernel, causal=causal, window=window,
                              softcap=softcap, scale=scale, kv_len=kv_len,
                              group=group, nq=nq)
@@ -276,10 +283,10 @@ def flash_bwd_dkv(q, k, v, do, lse, delta, *, group, causal, window, softcap,
             pl.BlockSpec((1, bk, hd), lambda bkv, ik, gg, iq: (bkv, ik, 0)),
             pl.BlockSpec((1, bq, hd),
                          lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq, 0)),
-            pl.BlockSpec((1, bq),
-                         lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq)),
-            pl.BlockSpec((1, bq),
-                         lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, 0, iq)),
+            pl.BlockSpec((1, 1, bq),
+                         lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, 0, iq)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, hd), lambda bkv, ik, gg, iq: (bkv, ik, 0)),
@@ -325,8 +332,8 @@ def _bwd_kv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, None]
-    delta = delta_ref[0][:, None]
+    lse = lse_ref[0].T                                # (bq, 1)
+    delta = delta_ref[0].T
 
     p, dchain = _recompute_p(q, k, iq, ik, bq, bk, causal=causal,
                              window=window, softcap=softcap, scale=scale,
@@ -424,7 +431,7 @@ def flash_bwd_fused(q, k, v, do, lse, delta, *, group, causal, window,
     assert Sq % bq == 0 and Skv % bk == 0
     nq, nk = Sq // bq, Skv // bk
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if dq_strategy is None:
         dq_strategy = "partials" if interpret else "alias"
     if dq_strategy not in ("partials", "alias"):
@@ -438,10 +445,10 @@ def flash_bwd_fused(q, k, v, do, lse, delta, *, group, causal, window,
         pl.BlockSpec((1, bk, hd), lambda bkv, ik, gg, iq: (bkv, ik, 0)),
         pl.BlockSpec((1, bq, hd),
                      lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq, 0)),
-        pl.BlockSpec((1, bq),
-                     lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq)),
-        pl.BlockSpec((1, bq),
-                     lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq)),
+        pl.BlockSpec((1, 1, bq),
+                     lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, 0, iq)),
+        pl.BlockSpec((1, 1, bq),
+                     lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, 0, iq)),
     ]
     dq_block = pl.BlockSpec((1, bq, hd),
                             lambda bkv, ik, gg, iq, g=g: (bkv * g + gg, iq, 0))

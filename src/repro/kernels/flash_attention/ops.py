@@ -107,7 +107,7 @@ def _vjp_bwd(causal, window, softcap, scale, block_q, block_k, bwd_strategy,
     dok = _pad_to(
         jnp.transpose(do, (0, 2, 3, 1, 4))
         .reshape(B * Hkv * G, S, hd).astype(jnp.float32), 1, bq)
-    delta = jnp.sum(dok * op, axis=-1)                    # (BH, Sq_padded)
+    delta = jnp.sum(dok * op, axis=-1)[:, None, :]       # (BH, 1, Sq_padded)
 
     common = dict(group=G, causal=causal, window=window, softcap=softcap,
                   scale=scale, kv_len=Skv, block_q=bq, block_k=bk)

@@ -8,7 +8,9 @@ remat-in-kernel scheme — and accumulates dH (grid t, v) and dW (grid v, t)
 into resident VMEM tiles.
 
 VMEM budget: tiles are (bt, D) for hidden and (D, bv) for the weight —
-``pick_blocks`` chooses bt/bv so both fit ~12 MB; supports gemma2's
+``pick_blocks`` chooses bt/bv so a grid step's windows and scratch fit
+under the TPU's 16 MiB default scoped VMEM (the backward also holds fp32
+dH and dW tiles, so it gets smaller blocks); supports gemma2's
 final-logit softcap with the exact tanh chain rule.
 
 The backward is a SINGLE grid sweep: each (bt, bv) logits tile is
@@ -22,6 +24,11 @@ vocab revisit (zero extra footprint); under the interpreter — whose
 pipeline does not thread output flushes back into aliased input reads —
 dH is staged as per-vocab-tile partials and reduced outside the kernel
 (test scale only).
+
+Per-token operands (labels, loss, lse, g) are (Tp, 1) columns with
+(bt, 1) blocks: 1-D (bt,) blocks are refused by the TPU compiler (XLA's
+1-D tiling does not match Mosaic's), and a column is what the (bt, bv)
+logits tile broadcasts against, so the kernels need no relayout.
 """
 
 from __future__ import annotations
@@ -33,14 +40,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.platform import pallas_interpret
+
 NEG_INF = -1e30
 
 
-def pick_blocks(D: int, vmem_budget: int = 12 * 2 ** 20):
-    """(bt, bv) such that (bt*D + D*bv + bt*D) * 4 bytes fits the budget."""
+def pick_blocks(D: int, itemsize: int = 4, *, backward: bool = False,
+                vmem_budget: int = 14 * 2 ** 20):
+    """Largest (bt, bv) whose per-step VMEM fits ``vmem_budget``: the
+    double-buffered h (bt, D) and w (D, bv) windows of ``itemsize``
+    bytes, plus for the backward the fp32 dH in/out windows and the dW
+    output window and scratch.  The budget leaves room under the 16 MiB
+    scoped limit for the (bt, bv) fp32 logits tiles."""
     for bt, bv in ((256, 512), (128, 256), (64, 128), (32, 128), (16, 128),
                    (8, 128)):
-        if (bt * D * 2 + D * bv) * 4 <= vmem_budget:
+        need = 2 * (bt * D + D * bv) * itemsize
+        if backward:
+            need += (4 * bt * D + 3 * D * bv) * 4
+        if need <= vmem_budget:
             return bt, bv
     return 8, 128
 
@@ -51,6 +68,11 @@ def clamp_block_t(bt: int, T: int, dtype=jnp.float32) -> int:
     huge block — bt=256 with T=20 would otherwise pad 12x."""
     sub = {4: 8, 2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
     return max(sub, min(-(-bt // sub) * sub, -(-T // sub) * sub))
+
+
+def _column(x, pad):
+    """(T,) per-token vector -> zero-padded (T + pad, 1) kernel column."""
+    return (jnp.pad(x, (0, pad)) if pad else x)[:, None]
 
 
 def _logits_tile(h, w, labels, iv, bv, V, softcap):
@@ -64,7 +86,7 @@ def _logits_tile(h, w, labels, iv, bv, V, softcap):
         s, dchain = z, None
     ids = iv * bv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = ids < V
-    onehot = (ids == labels[:, None]).astype(jnp.float32)
+    onehot = (ids == labels).astype(jnp.float32)      # labels: (bt, 1)
     s = jnp.where(valid, s, NEG_INF)
     return s, dchain, onehot, valid
 
@@ -100,27 +122,27 @@ def _fwd_kernel(h_ref, w_ref, lab_ref, loss_ref, lse_ref,
     @pl.when(iv == nv - 1)
     def _final():
         lse = m_sc[...] + jnp.log(jnp.maximum(l_sc[...], 1e-30))
-        loss_ref[...] = (lse - c_sc[...])[:, 0]
-        lse_ref[...] = lse[:, 0]
+        loss_ref[...] = lse - c_sc[...]
+        lse_ref[...] = lse
 
 
 def xent_fwd(h, w, labels, *, softcap=0.0, block_t=None, block_v=None,
              interpret=None):
     T, D = h.shape
     V = w.shape[1]
-    bt0, bv0 = pick_blocks(D)
+    bt0, bv0 = pick_blocks(D, h.dtype.itemsize)
     bt = block_t or bt0
     bv = block_v or bv0
     bt = clamp_block_t(bt, T, h.dtype)
     padT = (-T) % bt
     padV = (-V) % bv
     hp = jnp.pad(h, ((0, padT), (0, 0))) if padT else h
-    labp = jnp.pad(labels, (0, padT)) if padT else labels
+    labp = _column(labels, padT)
     wp = jnp.pad(w, ((0, 0), (0, padV))) if padV else w
     Tp, Vp = T + padT, V + padV
     nt, nv = Tp // bt, Vp // bv
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
     kern = functools.partial(_fwd_kernel, V=V, softcap=softcap, nv=nv)
     loss, lse = pl.pallas_call(
@@ -129,15 +151,15 @@ def xent_fwd(h, w, labels, *, softcap=0.0, block_t=None, block_v=None,
         in_specs=[
             pl.BlockSpec((bt, D), lambda it, iv: (it, 0)),
             pl.BlockSpec((D, bv), lambda it, iv: (0, iv)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
+            pl.BlockSpec((bt, 1), lambda it, iv: (it, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
-            pl.BlockSpec((bt,), lambda it, iv: (it,)),
+            pl.BlockSpec((bt, 1), lambda it, iv: (it, 0)),
+            pl.BlockSpec((bt, 1), lambda it, iv: (it, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bt, 1), jnp.float32),
@@ -146,7 +168,7 @@ def xent_fwd(h, w, labels, *, softcap=0.0, block_t=None, block_v=None,
         ],
         interpret=interpret,
     )(hp, wp, labp)
-    return loss[:T], lse[:T]
+    return loss[:T, 0], lse[:T, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -162,9 +184,9 @@ def _bwd_dlog(h_ref, w_ref, lab_ref, lse_ref, g_ref, iv, *, V, softcap):
     w = w_ref[...].astype(jnp.float32)
     s, dchain, onehot, valid = _logits_tile(h, w, lab_ref[...], iv, bv, V,
                                             softcap)
-    p = jnp.exp(s - lse_ref[...][:, None])
+    p = jnp.exp(s - lse_ref[...])
     p = jnp.where(valid, p, 0.0)
-    dlog = (p - onehot) * g_ref[...][:, None]
+    dlog = (p - onehot) * g_ref[...]
     if dchain is not None:
         dlog = dlog * dchain
     return h, w, dlog
@@ -248,30 +270,28 @@ def xent_bwd(h, w, labels, lse, g, *, softcap=0.0, block_t=None,
     interpreting, alias on TPU."""
     T, D = h.shape
     V = w.shape[1]
-    bt0, bv0 = pick_blocks(D)
+    bt0, bv0 = pick_blocks(D, h.dtype.itemsize, backward=True)
     bt = block_t or bt0
     bv = block_v or bv0
     bt = clamp_block_t(bt, T, h.dtype)
     padT = (-T) % bt
     padV = (-V) % bv
     hp = jnp.pad(h, ((0, padT), (0, 0))) if padT else h
-    labp = jnp.pad(labels, (0, padT)) if padT else labels
-    lsep = jnp.pad(lse, (0, padT)) if padT else lse
-    gp = jnp.pad(g, (0, padT)) if padT else g
+    labp, lsep, gp = (_column(a, padT) for a in (labels, lse, g))
     wp = jnp.pad(w, ((0, 0), (0, padV))) if padV else w
     Tp, Vp = T + padT, V + padV
     nt, nv = Tp // bt, Vp // bv
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if dh_strategy is None:
         dh_strategy = "partials" if interpret else "alias"
 
     in_specs = [
         pl.BlockSpec((bt, D), lambda iv, it: (it, 0)),
         pl.BlockSpec((D, bv), lambda iv, it: (0, iv)),
-        pl.BlockSpec((bt,), lambda iv, it: (it,)),
-        pl.BlockSpec((bt,), lambda iv, it: (it,)),
-        pl.BlockSpec((bt,), lambda iv, it: (it,)),
+        pl.BlockSpec((bt, 1), lambda iv, it: (it, 0)),
+        pl.BlockSpec((bt, 1), lambda iv, it: (it, 0)),
+        pl.BlockSpec((bt, 1), lambda iv, it: (it, 0)),
     ]
     dw_spec = pl.BlockSpec((D, bv), lambda iv, it: (0, iv))
     dw_shape = jax.ShapeDtypeStruct((D, Vp), jnp.float32)

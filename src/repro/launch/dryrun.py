@@ -1,4 +1,7 @@
 import os
+# a CPU-only rehearsal: 512 virtual host devices back the production
+# meshes, even on a host with a TPU (which would otherwise be the backend)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 

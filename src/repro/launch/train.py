@@ -1,17 +1,10 @@
 """Training launcher.
 
-Two modes:
-
-* ``--mode sim`` (default; runs anywhere) — full Ampere / baseline
-  federated training at smoke scale on synthetic non-IID data: the same
-  orchestration code (core/uit.py, core/baselines/*) the pod deployment
-  uses, including cohort sampling, dropout, straggler deadlines,
-  checkpoint/restart and the activation store.
-* ``--mode pod`` — binds the production mesh (requires real devices or the
-  dry-run's forced host-device count) and runs the jitted steps under the
-  sharded configuration.  On this CPU container it is exercised through
-  ``repro.launch.dryrun``; on a TPU pod the same entry point trains for
-  real.
+Full Ampere / baseline federated training on synthetic non-IID data
+through the trainers in core/uit.py and core/baselines/*: cohort
+sampling, dropout, straggler deadlines, checkpoint/restart and the
+activation store.  ``--smoke`` selects the reduced same-family config
+(CPU scale); without it the published widths are built.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch mobilenet-l \
@@ -35,6 +28,7 @@ from repro.core.baselines import FedAvgTrainer, SFLTrainer
 from repro.core.uit import AmpereTrainer
 from repro.data import federate, make_dataset_for_model
 from repro.models import build_model
+from repro.platform import enable_compile_cache
 
 
 def build_run_cfg(args) -> RunConfig:
@@ -95,6 +89,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))

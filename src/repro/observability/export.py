@@ -47,7 +47,7 @@ def _json_safe(v):
         return {str(k): _json_safe(x) for k, x in v.items()}
     try:
         return v.item()          # numpy / jax scalars
-    except Exception:
+    except (AttributeError, TypeError, ValueError):
         return repr(v)
 
 

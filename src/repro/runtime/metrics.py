@@ -82,6 +82,6 @@ def _to_py(v):
             return v.item()
         if isinstance(v, (np.floating, np.integer)):
             return v.item()
-    except Exception:
+    except (TypeError, ValueError):   # not a scalar; a device error raises
         pass
     return v

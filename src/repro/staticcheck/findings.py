@@ -26,7 +26,7 @@ SEVERITIES = ("error", "warning")
 
 #: bump when a check's semantics change enough to invalidate cached
 #: kernel-analysis results (see kernel_analyzer caching)
-ANALYZER_VERSION = 1
+ANALYZER_VERSION = 2
 
 
 @dataclasses.dataclass(frozen=True)

@@ -18,8 +18,11 @@ exercise:
     the window index does not change).
 (b) **block alignment** — (sublane, lane) tile requirements per dtype:
     the sublane dim must be a multiple of 8/16/32 for 4/2/1-byte types
-    (no full-dim exemption: the PR 5 ``S=20 -> bq=20`` bug *was* the
-    full dim), the lane dim a multiple of 128 or the whole array dim.
+    (no full-dim exemption: the ``S=20 -> bq=20`` bug *was* the full
+    dim), or 1 over an array dim of 1; the lane dim a multiple of 128 or
+    the whole array dim.  A 1-D block must be the whole array or a
+    multiple of 1024 elements.  These are the TPU compiler's rules: it
+    refused a (1, bq) block over (BH, S) and a (256,) block over (2048,).
 (c) **per-grid-step VMEM footprint** — double-buffered in/out windows
     plus scratch vs the ~16 MiB/core budget.
 (d) **write-before-read for outputs** — output windows are undefined on
@@ -46,6 +49,7 @@ from repro.staticcheck.findings import ANALYZER_VERSION, Finding
 
 SUBLANE_BY_ITEMSIZE = {4: 8, 2: 16, 1: 32}
 LANE = 128
+LANE_1D = 1024    # XLA tiles a 1-D array T(1024); Mosaic needs the same
 
 
 @dataclasses.dataclass
@@ -130,9 +134,13 @@ def trace_pallas_calls(fn, args) -> List:
 
 
 def _block_ints(block_shape) -> Tuple[int, ...]:
-    # mapped (None / pl.Squeezed) dims occupy one element of the window
-    return tuple(int(d) if isinstance(d, (int, np.integer)) else 1
-                 for d in block_shape)
+    # block dims arrive as ints or ``pl.Blocked(block_size)``; mapped
+    # (None / pl.Squeezed) dims occupy one element of the window
+    def size(d):
+        if isinstance(d, (int, np.integer)):
+            return int(d)
+        return int(getattr(d, "block_size", 1) or 1)
+    return tuple(size(d) for d in block_shape)
 
 
 def _eval_index_map(bm, idx) -> Tuple[int, ...]:
@@ -181,6 +189,7 @@ def _ref_accesses(kernel_jaxpr, n_operands: int):
     cond) and ``pjit``/``scan`` sub-jaxprs with positional ref mapping.
     """
     from jax import core as jcore
+    from jax.extend.core import Var
 
     acc: Dict[int, List[Tuple[str, bool]]] = {i: [] for i in
                                               range(n_operands)}
@@ -188,12 +197,12 @@ def _ref_accesses(kernel_jaxpr, n_operands: int):
            if i < n_operands}
 
     def ref_of(var):
-        return env.get(var) if isinstance(var, jcore.Var) else None
+        return env.get(var) if isinstance(var, Var) else None
 
     def walk(jaxpr, local_env, conditional):
         def rid(var):
             return (local_env.get(var)
-                    if isinstance(var, jcore.Var) else None)
+                    if isinstance(var, Var) else None)
 
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
@@ -286,9 +295,10 @@ def analyze_pallas_eqn(eqn, *, config_name: str, path: str,
                     for a, b in eqn.params.get("input_output_aliases", ()))
     n_idx = gm.num_index_operands
     n_in, n_out = gm.num_inputs, gm.num_outputs
-    name = getattr(eqn.params.get("name_and_src_info"), "name",
-                   "pallas_call")
     kernel_jaxpr = eqn.params["jaxpr"]
+    # the kernel function's name ("_fwd_kernel at <file>:<line>")
+    src = getattr(kernel_jaxpr.debug_info, "func_src_info", None) or ""
+    name = eqn.params.get("name") or src.split(" ")[0] or "pallas_call"
     findings: List[Finding] = []
 
     # ref accesses: kernel invars are [index ops..., inputs..., outputs...,
@@ -323,7 +333,7 @@ def analyze_pallas_eqn(eqn, *, config_name: str, path: str,
         kind = "in" if pos < n_in else "out"
         index = pos if pos < n_in else pos - n_in
         block = _block_ints(bm.block_shape)
-        sds = bm.array_shape_dtype
+        sds = bm.array_aval
         dtype = np.dtype(sds.dtype)
         ref_pos = n_idx + pos
         acc = accesses[ref_pos]
@@ -341,9 +351,21 @@ def analyze_pallas_eqn(eqn, *, config_name: str, path: str,
 
         # (b) block alignment vs per-dtype tile requirements
         sub_req = SUBLANE_BY_ITEMSIZE.get(dtype.itemsize, 8)
+        if len(block) == 1:
+            if block[0] != op.array_shape[0] and block[0] % LANE_1D:
+                findings.append(Finding(
+                    rule="block-misaligned", severity="error", path=path,
+                    line=0,
+                    message=f"{name}: {op.origin} 1-D block {block} is "
+                            f"neither the whole array {op.array_shape} nor "
+                            f"a multiple of XLA's {LANE_1D}-element 1-D "
+                            "tile (use a 2-D layout)",
+                    context=config_name,
+                    detail=f"{name}/{op.origin}/1d"))
         if len(block) >= 2:
             sublane, lane = block[-2], block[-1]
-            if sublane > 1 and sublane % sub_req:
+            if (sublane % sub_req if sublane > 1
+                    else op.array_shape[-2] > 1):
                 findings.append(Finding(
                     rule="block-misaligned", severity="error", path=path,
                     line=0,

@@ -83,8 +83,8 @@ def _fa_fwd_args(BH, BKV, Sq, Skv, hd, dtype="float32"):
 
 def _fa_bwd_args(BH, BKV, Sq, Skv, hd, dtype="float32"):
     return _fa_fwd_args(BH, BKV, Sq, Skv, hd, dtype) + [
-        _sds((BH, Sq, hd), "float32"), _sds((BH, Sq), "float32"),
-        _sds((BH, Sq), "float32")]
+        _sds((BH, Sq, hd), "float32"), _sds((BH, 1, Sq), "float32"),
+        _sds((BH, 1, Sq), "float32")]
 
 
 def _build_flash_fwd(BKV=2, G=2, Sq=256, Skv=256, hd=64, bq=128, bk=128,

@@ -129,7 +129,7 @@ def test_flash_attention_rejects_unknown_strategies():
             q, k, v, True, 0, 0.0, scale, 16, 16, "partial")))(q)
     qk = jnp.zeros((2, 16, 16), jnp.float32)
     kv = jnp.zeros((2, 16, 16), jnp.float32)
-    row = jnp.zeros((2, 16), jnp.float32)
+    row = jnp.zeros((2, 1, 16), jnp.float32)
     with pytest.raises(ValueError, match="dq_strategy"):
         K.flash_bwd_fused(qk, kv, kv, qk, row, row, group=1, causal=True,
                           window=0, softcap=0.0, scale=1.0, kv_len=16,
@@ -151,7 +151,7 @@ def test_flash_attention_fused_alias_scratch_case():
     _, (qp, kp, vp, op, lsep, _) = fa_ops._fwd(q, k, v, True, 0, 0.0, 0.25,
                                                bq, bk)
     do = jnp.asarray(rng.normal(0, 1, op.shape), jnp.float32)
-    delta = jnp.sum(do * op, axis=-1)
+    delta = jnp.sum(do * op, axis=-1)[:, None, :]
     common = dict(group=G, causal=True, window=0, softcap=0.0, scale=0.25,
                   kv_len=S, block_q=bq, block_k=bk)
     alias = K.flash_bwd_fused(qp, kp, vp, do, lsep, delta,
@@ -301,3 +301,19 @@ def test_ssd_chunked_matches_sequential_decode():
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h_final), np.asarray(h),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True),
+                                               ("gpu", None)])
+def test_pallas_interpret_follows_the_backend(monkeypatch, backend,
+                                              interpret):
+    """Compiled on a TPU, interpreted on the CPU, and no silent
+    interpreter fallback on any other backend."""
+    from repro.platform import pallas_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            pallas_interpret()
+    else:
+        assert pallas_interpret() is interpret

@@ -327,3 +327,18 @@ def test_epoch_indices_match_batches_draw():
     assert idx.shape == (2, 8)
     for got, b in zip(idx, via_batches):
         np.testing.assert_array_equal(data["labels"][got], b)
+
+
+def test_server_batch_larger_than_pool_fails_loudly():
+    """A server batch the consolidated pool cannot fill would run no
+    server step and report a NaN loss; the phase must refuse instead."""
+    m, run, clients, test = _setup("vit-s")
+    run = replace(run, fed=replace(run.fed, server_batch_size=128))
+    tr = AmpereTrainer(m, run, clients, test, patience=50)
+    dev, srv, aux = tr._init_states(jax.random.PRNGKey(0))
+    dev_state = {"device": dev, "aux": aux}
+    store = ActivationStore(seed=0)
+    tr.generate_activations(dev_state, store)
+    assert store.num_samples() < 128
+    with pytest.raises(ValueError, match="server batch 128 exceeds"):
+        tr.run_server_phase(dev_state, srv, store, max_epochs=1)

@@ -133,6 +133,36 @@ def test_misaligned_block_is_flagged():
     assert all(f.severity == "error" for f in findings)
 
 
+# geometries the TPU compiler refused in the kernels: flash attention's
+# (1, bq) lse block over (BH, S), xent's 1-D (bt,) label block, and the
+# SSD kernel's (1, Q) dt block over (B*nc*H, Q)
+REFUSED_GEOMETRIES = {
+    "flash_lse_row": ((1, 128), (32, 256), lambda i: (i, 0)),
+    "xent_labels_1d": ((256,), (2048,), lambda i: (i,)),
+    "ssd_dt_row": ((1, 256), (8, 256), lambda i: (i, 0)),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(REFUSED_GEOMETRIES))
+def test_refused_geometry_is_flagged(geometry):
+    from jax.experimental import pallas as pl
+
+    block, shape, index_map = REFUSED_GEOMETRIES[geometry]
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 1.0
+
+    fn, args = _toy_call(
+        kernel, grid=(shape[0] // block[0],),
+        in_specs=[pl.BlockSpec(block, index_map)],
+        out_spec=pl.BlockSpec(block, index_map),
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+        args=[jax.ShapeDtypeStruct(shape, jnp.float32)])
+    _, findings = analyze_traceable(fn, args, config_name="toy",
+                                    path="toy.py")
+    assert [f.rule for f in findings] == ["block-misaligned"] * 2
+
+
 def test_output_read_before_write_is_flagged():
     """``o_ref[...] += x`` reads the undefined output window on its
     first visit — must be flagged even though the code 'looks like' a
