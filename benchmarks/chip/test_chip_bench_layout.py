@@ -1,0 +1,103 @@
+"""Every cell of ``BENCHMARK.json`` resolves, by name, to the files the
+harness loads, and the file keeps the benchmark contract's shape."""
+
+import json
+import pathlib
+import re
+
+import pytest
+
+BENCH = pathlib.Path(__file__).resolve().parent
+ROOT = BENCH.parent.parent
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def _load_registry():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_registry", BENCH / "harness" / "registry.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_top_level_keys_and_limits():
+    assert set(SPEC) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert SPEC["paths"] == ["benchmarks/chip"]
+    assert SPEC["command"][1] == "benchmarks/chip/run.py"
+    assert 1 <= SPEC["run_seconds"] <= 51
+    assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+    names = ([c["name"] for c in SPEC["configs"]] + CELLS
+             + [m["name"] for m in SPEC["end_to_end"] + SPEC["per_layer"]])
+    assert len(names) == len(set(names))
+    for n in names:
+        assert NAME.match(n), n
+    for m in SPEC["end_to_end"] + SPEC["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+    for m in SPEC["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    assert any(m["name"] == "setup_s" for m in SPEC["end_to_end"])
+
+
+def test_configs_files_and_reduced():
+    for c in SPEC["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["file"] == f"benchmarks/chip/configs/{c['name']}.json"
+        data = json.loads((ROOT / c["file"]).read_text())
+        assert data["name"] == c["name"]
+        assert data["reduced"] == c["reduced"] == []
+        assert (BENCH / "configs" / f"{c['name']}.py").is_file()
+        assert any(w["config"] == c["name"] for w in SPEC["workloads"])
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_resolves_by_name(cell):
+    reg = _load_registry()
+    entry = reg.cell_entry(SPEC, cell)
+    assert set(entry) == {"name", "config", "traffic", "chips", "why"}
+    assert entry["chips"] == 1 and len(entry["why"]) <= 200
+    w = reg.workload(cell)
+    assert w["config"] == entry["config"]
+    assert w["driver"] == entry["traffic"]
+    reg.config(w["config"])
+    drv = reg.driver(entry["traffic"])
+    for fn in ("setup", "prime", "size", "window", "free", "follow",
+               "numbers"):
+        assert callable(getattr(drv, fn))
+    ref = reg.reference(w["config"])
+    for fn in ("init", "device_forward", "aux_loss", "server_loss",
+               "flops_per_sample"):
+        assert callable(getattr(ref, fn))
+    e2e, per_layer = reg.cell_metrics(SPEC, cell)
+    assert "setup_s" in {m["name"] for m in e2e} and len(e2e) >= 2
+    assert per_layer
+    for m in per_layer:
+        assert callable(reg.metric_reader(m["name"]).read)
+    assert set(w["limits"]) and all(v >= 0 for v in w["limits"].values())
+
+
+def test_per_layer_metrics_move_what_their_cells_report():
+    reg = _load_registry()
+    layers = {m["layer"] for m in SPEC["per_layer"]}
+    for m in SPEC["per_layer"]:
+        assert m["moves"] in {e["name"] for e in SPEC["end_to_end"]}
+        for cell in m["workloads"]:
+            e2e, _ = reg.cell_metrics(SPEC, cell)
+            assert m["moves"] in {e["name"] for e in e2e}, (m["name"], cell)
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    assert layers <= {"server phase", "device round", "device"}
+
+
+def test_files_under_paths_are_named_from_names():
+    for p in BENCH.rglob("*"):
+        rel = p.relative_to(ROOT).as_posix()
+        if "/." in "/" + rel or "__pycache__" in rel:
+            continue
+        assert re.match(r"^[A-Za-z0-9_./-]+$", rel), rel
