@@ -1,0 +1,18 @@
+"""eval_wait_share.server: the merged evaluation's waits on the device
+over the window, in percent: the program's ``eval.wait`` spans (each
+eval batch's host read of its loss and accuracy) whose parent is a
+``merged_eval`` span.  At most ``merged_eval_share``: the rest of the
+evaluation is its host loop, batch uploads and dispatch.  Nothing to
+read in a program without the span."""
+
+
+def read(ctx):
+    if ctx.driver != "server":
+        return None
+    evals = {getattr(s, "span_id", None) for s in ctx.spans
+             if s.name == "merged_eval"} - {None}
+    waits = [s.dur_wall for s in ctx.spans if s.name == "eval.wait"
+             and getattr(s, "parent_id", None) in evals]
+    if not waits:
+        return None
+    return 100.0 * sum(waits) / ctx.info["window_s"]

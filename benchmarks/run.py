@@ -137,8 +137,7 @@ def main(argv=None) -> None:
     ap.add_argument("--gate-threshold", type=float, default=0.25)
     ap.add_argument("--profile", action="store_true",
                     help="wrap the benchmark run in jax.profiler.trace "
-                         "(dump under results/profile) and activate "
-                         "kernel-site trace annotations")
+                         "(dump under results/profile)")
     args = ap.parse_args(argv)
     from repro.platform import enable_compile_cache
     enable_compile_cache()

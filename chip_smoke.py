@@ -16,16 +16,18 @@ Everything runs in this one process (a chip belongs to one process):
    epochs over seeded synthetic data and random weights.
 
 It prints one JSON line per kernel and per model (errors, parameter
-count, compile seconds, per-phase wall seconds of this smoke run — not a
-benchmark — and the first and last device-round and server-epoch
-losses), then as its last line ``{"ok": true, "device": {...}}``.  It
+count, the program's compile counts — jit traces, backend compiles,
+persistent-cache loads and their seconds — per-phase wall seconds of
+this smoke run — not a benchmark — and the first and last device-round
+and server-epoch losses), then as its last line ``{"ok": true,
+"device": {...}}``.  It
 exits non-zero, without that line, when JAX finds no TPU, a parameter of
 the trained state is not on the TPU, a loss is not finite, the server
 loss does not fall, a kernel misses its oracle, or anything raises.
 
 The compile cache is ``$JAX_COMPILATION_CACHE_DIR`` when set, else
-``<repo>/.jax_cache``; a second run with a warm cache reports fewer
-compile seconds.
+``<repo>/.jax_cache``; a second run with a warm cache reports cache
+loads in place of compiles.
 """
 
 import json
@@ -44,34 +46,17 @@ TRAIN_SAMPLES = 3840          # 15 server batches of 256 per epoch
 EVAL_SAMPLES = 512
 DEVICE_ROUNDS = 2
 SERVER_EPOCHS = 2
+# what the program's compile counter puts on a span (repro.observability)
+JIT_KEYS = ("traces", "compiles", "cache_loads", "jit_s")
 
 
 def fail(msg):
     raise SystemExit(f"chip_smoke: FAILED: {msg}")
 
 
-class CompileClock:
-    """Seconds JAX spends compiling (cache lookups included) and
-    persistent-cache hits, from JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_dur(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def read(self):
-        return self.seconds, self.cache_hits
+def jit_work(*attrs):
+    """The compile counts summed over span attrs (or ``jit_outside``)."""
+    return {k: sum(a.get(k, 0) for a in attrs) for k in JIT_KEYS}
 
 
 def tpu_device():
@@ -245,17 +230,16 @@ def _assert_on(device, tree, what):
             fail(f"{what}: a parameter leaf is on {where}, not {device}")
 
 
-def model_phase(arch, device, clock):
+def model_phase(arch, device, tracer):
     import jax
 
     from repro.experiments import run_experiment
 
     spec = model_spec(arch)
-    c0, h0 = clock.read()
     t0 = time.perf_counter()
-    out = run_experiment(spec, write_results=False)
+    with tracer.span(f"model.{arch}") as sp:
+        out = run_experiment(spec, write_results=False)
     wall = time.perf_counter() - t0
-    c1, h1 = clock.read()
 
     res = out["results"]["ampere"]
     hist = res["history"]
@@ -270,7 +254,7 @@ def model_phase(arch, device, clock):
         "phase": "model", "arch": arch, "smoke_run_not_benchmark": True,
         "params": int(sum(x.size for x in
                           jax.tree.leaves(res["merged_params"]))),
-        "compile_s": c1 - c0, "cache_hits": h1 - h0, "wall_s": wall,
+        "jit": jit_work(sp.attrs), "wall_s": wall,
         "phase_wall_s": phase_wall,
         # data synthesis, init and activation generation: outside steps
         "outside_phase_steps_s": wall - sum(phase_wall.values()),
@@ -298,17 +282,19 @@ def main():
     cache_dir = enable_compile_cache()
     import jax
 
+    from repro.observability import Tracer
+
     device = tpu_device()
-    clock = CompileClock()
+    tracer = Tracer()       # the program's compile counter, a span a phase
     t0 = time.perf_counter()
-    kernel_phase(fa=dict(B=1, S=4096, Hkv=8, G=2, hd=128),
-                 xent=dict(T=4096, D=2048, V=151936),
-                 ssd=dict(B=1, nc=16, Q=256, H=32, P=64, N=128))
+    with tracer.span("kernels"):
+        kernel_phase(fa=dict(B=1, S=4096, Hkv=8, G=2, hd=128),
+                     xent=dict(T=4096, D=2048, V=151936),
+                     ssd=dict(B=1, nc=16, Q=256, H=32, P=64, N=128))
     for arch in MODELS:
-        model_phase(arch, device, clock)
-    compile_s, hits = clock.read()
-    print(json.dumps({"phase": "total", "compile_s": compile_s,
-                      "cache_hits": hits,
+        model_phase(arch, device, tracer)
+    total = jit_work(tracer.jit_outside, *(e.attrs for e in tracer.events))
+    print(json.dumps({"phase": "total", "jit": total,
                       "wall_s": time.perf_counter() - t0,
                       "compile_cache_dir": cache_dir}), flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -2,19 +2,17 @@
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import losses
+from repro.core import losses, steps
+from repro.observability.tracer import NULL_TRACER
 
 
 def make_eval_step(model):
     cfg = model.cfg
 
-    @jax.jit
     def eval_step(params, batch):
         if model.kind == "lm":
             out = model.apply(params, batch["tokens"], remat="none")
@@ -27,11 +25,15 @@ def make_eval_step(model):
             acc = m["acc"]
         return loss, acc
 
-    return eval_step
+    return jax.jit(steps.named("eval_step", eval_step))
 
 
 def evaluate(model, params, dataset, batch_size: int = 64,
-             max_batches: int = 50, eval_step=None) -> dict:
+             max_batches: int = 50, eval_step=None,
+             tracer=NULL_TRACER) -> dict:
+    """Mean loss and accuracy over the first ``max_batches`` full
+    batches; each batch's wait for its two scalars is an ``eval.wait``
+    span of ``tracer``."""
     step = eval_step or make_eval_step(model)
     n = len(dataset)
     batch_size = min(batch_size, n)
@@ -40,8 +42,9 @@ def evaluate(model, params, dataset, batch_size: int = 64,
         idx = np.arange(s, s + batch_size)
         batch = {k: v[idx] for k, v in dataset.arrays.items()}
         loss, acc = step(params, batch)
-        ls.append(float(loss))
-        accs.append(float(acc))
+        with tracer.span("eval.wait", track="eval"):
+            ls.append(float(loss))
+            accs.append(float(acc))
         cnt += 1
         if cnt >= max_batches:
             break

@@ -48,6 +48,18 @@ from repro.optim import make_optimizer, make_schedule, clip_by_global_norm
 from repro.sharding import shard
 
 
+def named(name: str, fn):
+    """``fn`` under ``jax.named_scope(name)``: a stable name for a jitted
+    step's ops in a profile, whatever builds the step.  Each call enters
+    a scope of its own (one ``named_scope`` used as a decorator keeps its
+    state on itself, which nested or concurrent traces would share)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 def _device_batch_slice(batch, idx):
     return jax.tree.map(lambda a: a[idx], batch)
 

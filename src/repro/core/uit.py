@@ -43,6 +43,7 @@ deprecation shims over it — prefer
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional
 
 import jax
@@ -58,6 +59,18 @@ from repro.models import build_model
 from repro.observability import NULL_OBS
 from repro.optim import make_schedule
 from repro.transport import QuorumError, cohort_exchange, required_quorum
+
+
+def _phase_span(name: str, track: str):
+    """Run the decorated phase entry inside one span of the trainer's
+    tracer: the whole call, so its pool upload and compile work too."""
+    def decorate(method):
+        @functools.wraps(method)
+        def traced(self, *args, **kwargs):
+            with self.obs.tracer.span(name, track=track):
+                return method(self, *args, **kwargs)
+        return traced
+    return decorate
 
 
 class AmpereTrainer:
@@ -107,19 +120,26 @@ class AmpereTrainer:
         self.history = self.runner.history
 
         # step functions (round state is donated: callers rebind per round)
-        self._device_round = jax.jit(steps.make_device_round_step(model, run_cfg),
-                                     donate_argnums=(0,))
+        self._device_round = jax.jit(
+            steps.named("device_round",
+                        steps.make_device_round_step(model, run_cfg)),
+            donate_argnums=(0,))
         # pool-fed federated round: the whole population's samples live on
         # device (uploaded once), the round state is donated, and each
         # round ships only a (K, H, b) int32 index matrix
         self._device_round_pool = jax.jit(
-            steps.make_device_round_pool_step(model, run_cfg),
+            steps.named("device_round",
+                        steps.make_device_round_pool_step(model, run_cfg)),
             donate_argnums=(0,))
-        self._server_step = jax.jit(steps.make_server_train_step(model, run_cfg))
+        self._server_step = jax.jit(
+            steps.named("server_step",
+                        steps.make_server_train_step(model, run_cfg)))
         # whole-epoch server phase: device-resident pool, donated state,
         # one host sync per epoch
-        self._server_epoch = jax.jit(steps.make_server_epoch_fn(model, run_cfg),
-                                     donate_argnums=(0,))
+        self._server_epoch = jax.jit(
+            steps.named("server_epoch",
+                        steps.make_server_epoch_fn(model, run_cfg)),
+            donate_argnums=(0,))
         self._sched = make_schedule(run_cfg.optim)
 
         # sizes for comm accounting
@@ -204,6 +224,7 @@ class AmpereTrainer:
     # ------------------------------------------------------------------
     # Phase 3: federated device training
     # ------------------------------------------------------------------
+    @_phase_span("device.phase", "device")
     def run_device_phase(self, dev_state, max_rounds: Optional[int] = None):
         if self.cuts is not None:
             raise ValueError(
@@ -223,13 +244,14 @@ class AmpereTrainer:
         total_bytes = sum(a.nbytes for c in self.clients
                           for a in c.dataset.arrays.values())
         resident = total_bytes <= self.run.device_pool_budget_mb * 2 ** 20
-        if resident:
-            pool_np, offsets = client_pool(self.clients)
-            pool_dev = {k: jnp.asarray(v) for k, v in pool_np.items()}
-            del pool_np
-        # both round steps donate their input state; copy once so the
-        # caller's buffers survive the first donation
-        dev_state = jax.tree.map(lambda a: jnp.array(a), dev_state)
+        with self.obs.tracer.span("device.pool_upload", track="device"):
+            if resident:
+                pool_np, offsets = client_pool(self.clients)
+                pool_dev = {k: jnp.asarray(v) for k, v in pool_np.items()}
+                del pool_np
+            # both round steps donate their input state; copy once so the
+            # caller's buffers survive the first donation
+            dev_state = jax.tree.map(lambda a: jnp.array(a), dev_state)
 
         def body(state, rnd, _plan):
             cohort = aggregation.sample_cohort(self.rng, fed, rnd)
@@ -459,7 +481,6 @@ class AmpereTrainer:
         model, run = self.model, self.run
 
         def make_step(p, split_cfg, aux_of):
-            @jax.jit
             def step(dev_state, batch):
                 inp = batch["tokens"] if model.kind == "lm" \
                     else batch["images"]
@@ -469,7 +490,7 @@ class AmpereTrainer:
                                              dev_state["device"], acts,
                                              batch, split_cfg)
                 return loss, m.get("acc", jnp.zeros(()))
-            return step
+            return jax.jit(steps.named("aux_eval_step", step))
 
         if self.cuts is None:
             steps_by_depth = {run.split.split_point: make_step(
@@ -483,8 +504,10 @@ class AmpereTrainer:
                              (lambda d=d: lambda s: s["aux"][f"p{d}"])())
                 for d in self.cuts.depths}
 
+        tracer = self.obs.tracer
+
         def eval_fn(dev_state, max_batches: int = 8, batch_size: int = 64):
-            with self.obs.tracer.span("aux_eval", track="eval") as sp:
+            with tracer.span("aux_eval", track="eval") as sp:
                 n = len(self.eval_data)
                 ls, accs = [], []
                 bs = min(batch_size, n)
@@ -494,8 +517,9 @@ class AmpereTrainer:
                              for k, v in self.eval_data.arrays.items()}
                     for step in steps_by_depth.values():
                         loss, acc = step(dev_state, batch)
-                        ls.append(float(loss))
-                        accs.append(float(acc))
+                        with tracer.span("eval.wait", track="eval"):
+                            ls.append(float(loss))
+                            accs.append(float(acc))
                 out = {"val_loss": float(np.mean(ls)),
                        "val_acc": float(np.mean(accs))}
                 sp.set(**out)
@@ -531,11 +555,10 @@ class AmpereTrainer:
         p = run.split.split_point
 
         def make_fwd(depth):
-            @jax.jit
             def fwd(device_params, inp):
                 return splitting.device_forward(model, device_params, inp,
                                                 depth)
-            return fwd
+            return jax.jit(steps.named("consolidate_fwd", fwd))
 
         if self.cuts is None:
             fwds = {None: make_fwd(p)}
@@ -589,23 +612,31 @@ class AmpereTrainer:
             return (bytes_cum[None] / comm_model.BANDWIDTH_BPS
                     + sum(client_extra.values()))
 
-        def submit(cid, shard, t_arr, cut):
-            if streams:
-                store.submit(cid, shard, t_arrival=t_arr, cut=cut)
-            elif cut is not None:
-                store.submit(cid, shard, cut=cut)
-            else:
-                store.submit(cid, shard)
+        tracer = self.obs.tracer
 
-        store.start_writer()
+        def submit(cid, shard, t_arr, cut):
+            with tracer.span("consolidate.submit", track="transfer"):
+                if streams:
+                    store.submit(cid, shard, t_arrival=t_arr, cut=cut)
+                elif cut is not None:
+                    store.submit(cid, shard, cut=cut)
+                else:
+                    store.submit(cid, shard)
+
+        store.start_writer(tracer=tracer)
         # double-buffered upload: batch k+1 transfers while k computes
-        for (cid, labels), inp in DevicePrefetcher(host_batches()):
+        feed = DevicePrefetcher(
+            tracer.iter_span(host_batches(), "feed.gather", "transfer/feed"),
+            tracer=tracer, track="transfer/feed")
+        for (cid, labels), inp in tracer.iter_span(
+                feed, "consolidate.feed_wait", "transfer"):
             cut = cut_of(cid)
             dev_params = (dev_state["device"] if cut is None
                           else self._device_prefix(dev_state["device"], cut))
-            shard = {"acts": np.asarray(fwds[cut](dev_params, inp),
-                                        np.float32),
-                     lab_key: labels}
+            with tracer.span("consolidate.fetch", track="transfer"):
+                shard = {"acts": np.asarray(fwds[cut](dev_params, inp),
+                                            np.float32),
+                         lab_key: labels}
             if transport is not None:
                 # each shard is one framed message; the idempotency key
                 # (client, shard index) is stable across retries and
@@ -642,7 +673,8 @@ class AmpereTrainer:
                 continue
             for shard, t_arr, cut in shards:
                 submit(cid, shard, t_arr, cut)
-        store.finish()
+        with tracer.span("consolidate.finish", track="transfer"):
+            store.finish()
         if faulty and failed:
             survivors = len(self.clients) - len(failed)
             need = required_quorum(len(self.clients), self.quorum_frac)
@@ -711,6 +743,7 @@ class AmpereTrainer:
     # ------------------------------------------------------------------
     # Phase 5: centralized server training on the consolidated set
     # ------------------------------------------------------------------
+    @_phase_span("server.phase", "server")
     def run_server_phase(self, dev_state, srv_params, store: ActivationStore,
                          max_epochs: Optional[int] = None):
         """Device-bound server phase.
@@ -726,6 +759,7 @@ class AmpereTrainer:
             return self._run_server_phase_hetero(dev_state, srv_params,
                                                  store, max_epochs)
         run = self.run
+        tracer = self.obs.tracer
         srv_state = steps.init_server_state(self.model, run, srv_params)
         srv_state, start_epoch = self.runner.restore("server", srv_state,
                                                      step_name="epoch")
@@ -742,11 +776,12 @@ class AmpereTrainer:
         resident = store.pool_nbytes() <= budget
         pool_dev = None
         if resident:
-            pool_dev = {k: jnp.asarray(v)
-                        for k, v in store.pool(dequantize=False).items()}
-            # the epoch fn donates its input state; copy once so the
-            # caller's srv_params buffers survive the first donation
-            srv_state = jax.tree.map(lambda a: jnp.array(a), srv_state)
+            with tracer.span("server.pool_upload", track="server"):
+                pool_dev = {k: jnp.asarray(v) for k, v in
+                            store.pool(dequantize=False).items()}
+                # the epoch fn donates its input state; copy once so the
+                # caller's srv_params buffers survive the first donation
+                srv_state = jax.tree.map(lambda a: jnp.array(a), srv_state)
 
         p = run.split.split_point
         epoch_sim_time = comm_model.ampere_server_epoch_time(
@@ -789,22 +824,29 @@ class AmpereTrainer:
                 else:
                     srv_state, losses = self._server_epoch(srv_state,
                                                            pool_dev, idx)
-                ls = np.asarray(losses, np.float64)  # ONE sync per epoch
+                with tracer.span("server.sync", track="server"):
+                    ls = np.asarray(losses, np.float64)  # ONE sync per epoch
             else:
                 acc = []
                 batches = store.batches(bs, epochs=1, dequantize=False)
-                for _, batch in DevicePrefetcher(
-                        (None, b) for b in batches):
+                feed = DevicePrefetcher(
+                    tracer.iter_span(((None, b) for b in batches),
+                                     "feed.gather", "server/feed"),
+                    tracer=tracer, track="server/feed")
+                for _, batch in tracer.iter_span(feed, "server.feed_wait",
+                                                 "server"):
                     srv_state, m = self._server_step(srv_state, batch)
                     acc.append(m["loss"])           # device scalar, no sync
-                ls = (np.asarray(jax.device_get(acc), np.float64) if acc
-                      else np.zeros((0,), np.float64))  # one epoch-end sync
+                with tracer.span("server.sync", track="server"):
+                    ls = (np.asarray(jax.device_get(acc), np.float64)
+                          if acc else np.zeros((0,), np.float64))  # one sync
             merged = splitting.merge_params(self.model, dev_state["device"],
                                             srv_state["server"], p)
             with self.obs.tracer.span("merged_eval", track="eval",
                                       epoch=epoch) as esp:
                 val = evaluate.evaluate(merged_model, merged, self.eval_data,
-                                        eval_step=eval_step)
+                                        eval_step=eval_step,
+                                        tracer=self.obs.tracer)
                 esp.set(val_loss=val["loss"], val_acc=val["acc"])
             return StepOutcome(
                 state=srv_state,
@@ -918,7 +960,8 @@ class AmpereTrainer:
             with self.obs.tracer.span("merged_eval", track="eval",
                                       epoch=epoch) as esp:
                 val = evaluate.evaluate(merged_model, merged, self.eval_data,
-                                        eval_step=eval_step)
+                                        eval_step=eval_step,
+                                        tracer=self.obs.tracer)
                 esp.set(val_loss=val["loss"], val_acc=val["acc"])
             return StepOutcome(
                 state=srv_state,

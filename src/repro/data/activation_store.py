@@ -35,6 +35,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.observability.tracer import NULL_TRACER
+
 
 class ActivationStore:
     def __init__(self, directory: Optional[str] = None,
@@ -60,20 +62,23 @@ class ActivationStore:
     # ------------------------------------------------------------------
     # Subprocess 1: receive & store
     # ------------------------------------------------------------------
-    def start_writer(self):
+    def start_writer(self, tracer=NULL_TRACER):
+        """Start the writer thread; each shard it stores is a
+        ``store.write`` span of ``tracer``."""
         # non-daemon: close()/finish() joins it, so the writer can never
         # race interpreter teardown mid-.npz-write
         if self._writer is None:
             self._writer = threading.Thread(target=self._writer_loop,
-                                            daemon=False)
+                                            args=(tracer,), daemon=False)
             self._writer.start()
 
-    def _writer_loop(self):
+    def _writer_loop(self, tracer):
         while True:
             item = self._q.get()
             if item is None:
                 break
-            self._store(*item)
+            with tracer.span("store.write", track="transfer/writer"):
+                self._store(*item)
 
     def submit(self, client_id: int, shard: dict,
                cut: Optional[int] = None):

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.data.partition import dirichlet_partition
 from repro.data.synthetic import Dataset
+from repro.observability.tracer import NULL_TRACER
 
 
 class ClientData:
@@ -150,15 +151,19 @@ class DevicePrefetcher:
     background thread, up to ``depth`` items ahead of the consumer, so
     the upload of batch k+1 overlaps the computation on batch k.
     ``meta`` passes through untouched (client ids, host-side slices).
-    Iteration yields ``(meta, device_tree)`` in producer order.
+    Iteration yields ``(meta, device_tree)`` in producer order.  Each
+    ``device_put`` is a ``feed.put`` span of ``tracer`` on ``track``, in
+    the background thread.
     """
 
-    def __init__(self, producer_iter, depth: int = 2):
+    def __init__(self, producer_iter, depth: int = 2, *,
+                 tracer=NULL_TRACER, track: str = "feed"):
         import jax
 
         def put(item):
             meta, tree = item
-            return meta, jax.device_put(tree)
+            with tracer.span("feed.put", track=track):
+                return meta, jax.device_put(tree)
 
         self._inner = Prefetcher(map(put, producer_iter), depth=depth)
 

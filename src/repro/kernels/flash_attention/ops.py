@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import kernel as K
-from repro.observability.profiling import annotate
 
 
 def _pad_to(x, axis, mult):
@@ -78,7 +77,7 @@ def _fwd(q, k, v, causal, window, softcap, scale, block_q, block_k):
     qp = _pad_to(qk, 1, bq)
     kp = _pad_to(kk, 1, bk)
     vp = _pad_to(vk, 1, bk)
-    with annotate("flash_fwd"):      # host dispatch/trace time (--profile)
+    with jax.named_scope("flash_fwd"):
         op, lsep = K.flash_fwd(qp, kp, vp, group=G, causal=causal,
                                window=window, softcap=softcap, scale=scale,
                                kv_len=Skv, block_q=bq, block_k=bk)
@@ -114,7 +113,7 @@ def _vjp_bwd(causal, window, softcap, scale, block_q, block_k, bwd_strategy,
     bwds = {"fused": K.flash_bwd_fused, "split": K.flash_bwd_dq_dkv}
     if bwd_strategy not in bwds:
         raise ValueError(f"unknown bwd_strategy: {bwd_strategy!r}")
-    with annotate(f"flash_bwd_{bwd_strategy}"):
+    with jax.named_scope(f"flash_bwd_{bwd_strategy}"):
         dq, dk, dv = bwds[bwd_strategy](qp, kp, vp, dok, lsep, delta,
                                         **common)
 

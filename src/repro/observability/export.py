@@ -32,6 +32,9 @@ from repro.observability.tracer import SpanRecord, Tracer
 from repro.transport.framing import crc32
 
 SPAN_LOG_FORMAT = "span-log-v1"
+# span links, written where set; logs written before they existed read
+# back with None
+_LINK_FIELDS = ("span_id", "parent_id", "thread")
 
 
 def _canonical(rec: dict) -> bytes:
@@ -82,6 +85,9 @@ def to_chrome_trace(tracer: Tracer) -> dict:
             dur = e.dur_wall * 1e6
         args = {k: _json_safe(v) for k, v in e.attrs.items()}
         args["clock"] = e.clock
+        for field in _LINK_FIELDS:
+            if getattr(e, field) is not None:
+                args[field] = getattr(e, field)
         if e.clock == "wall" and e.t_sim is not None:
             args["sim_t"] = e.t_sim
             if e.dur_sim is not None:
@@ -191,6 +197,9 @@ def write_span_log(tracer: Tracer, path: str) -> int:
                 rec["t_sim"] = e.t_sim
             if e.dur_sim is not None:
                 rec["dur_sim"] = e.dur_sim
+            for field in _LINK_FIELDS:
+                if getattr(e, field) is not None:
+                    rec[field] = getattr(e, field)
             rec["_crc"] = crc32(_canonical(rec))
             f.write(json.dumps(rec) + "\n")
             n += 1
@@ -237,7 +246,8 @@ def read_span_log(path: str, *, strict: bool = True) -> List[SpanRecord]:
                 t_sim=rec.get("t_sim"), dur_sim=rec.get("dur_sim"),
                 clock=rec.get("clock", "wall"),
                 depth=int(rec.get("depth", 0)),
-                attrs=rec.get("attrs", {})))
+                attrs=rec.get("attrs", {}),
+                **{f: rec.get(f) for f in _LINK_FIELDS}))
     if strict and declared is not None and len(out) != int(declared):
         raise ValueError(
             f"{path}: truncated span log — header declares {declared} "

@@ -1,75 +1,30 @@
 """Optional ``jax.profiler`` coupling.
 
-The tracer's own spans are host-side; to line them up with device
-activity, ``--profile`` on ``scripts/run_experiment.py`` /
-``benchmarks/run.py`` wraps the run in ``jax.profiler.trace(logdir)``
-and flips :func:`enable_annotations`, after which
-
-* every :meth:`~repro.observability.tracer.Tracer.span` of a
-  ``profile=True`` tracer also enters a ``jax.profiler.TraceAnnotation``
-  (visible on the profiler's host track), and
-* the kernel entry points (:func:`annotate` call sites in
-  ``repro.kernels.*.ops``) emit named annotations around their
-  ``pallas_call`` dispatches.  Inside a ``jit`` trace these mark
-  trace-time only; the device-side story comes from the XLA op names the
-  profiler records anyway — the annotations exist to bracket *host*
-  dispatch and compile time.
-
-Everything degrades to a shared no-op when jax is absent or profiling is
-off, so importing this module never costs anything on the hot path.
+``--profile`` on ``scripts/run_experiment.py`` / ``benchmarks/run.py``
+wraps the run in :func:`profile_run` and builds its tracers with
+``profile=True``: every tracer span, on every thread, then also holds a
+``jax.profiler.TraceAnnotation`` of its name, so the profiler's host
+planes show the program's spans on the device trace's clock.  The
+jitted steps and the kernels' entry points carry ``jax.named_scope``
+names, which the profiler records in the device ops' metadata.
 """
 
 from __future__ import annotations
 
 import contextlib
 
-_ACTIVE = False
-
-
-def enable_annotations(on: bool = True):
-    """Globally enable :func:`annotate` (``--profile`` flips this)."""
-    global _ACTIVE
-    _ACTIVE = bool(on)
-
-
-def annotations_active() -> bool:
-    return _ACTIVE
-
-
-_NULL = contextlib.nullcontext()
-
-
-def trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation(name)`` or a shared no-op."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:
-        return _NULL
-
-
-def annotate(name: str):
-    """Kernel-call hook: a profiler annotation when profiling is on."""
-    if not _ACTIVE:
-        return _NULL
-    return trace_annotation(name)
-
 
 @contextlib.contextmanager
 def profile_run(logdir: str):
-    """``jax.profiler.trace`` around a whole run, annotations enabled.
+    """``jax.profiler.trace`` around a whole run.
 
     Yields the logdir (``tensorboard --logdir`` / Perfetto opens it).
-    Missing jax profiler support degrades to annotations-only.
+    Missing jax profiler support runs the body untraced.
     """
-    enable_annotations(True)
     try:
-        try:
-            import jax
-            cm = jax.profiler.trace(logdir)
-        except Exception:
-            cm = contextlib.nullcontext()
-        with cm:
-            yield logdir
-    finally:
-        enable_annotations(False)
+        import jax
+        cm = jax.profiler.trace(logdir)
+    except (ImportError, AttributeError):
+        cm = contextlib.nullcontext()
+    with cm:
+        yield logdir

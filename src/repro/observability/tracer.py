@@ -25,6 +25,20 @@ or the shared :data:`NULL_TRACER`) costs one attribute check per call
 and records nothing, so it can be threaded unconditionally through hot
 paths.
 
+Spans nest per thread: each thread keeps its own stack of open spans,
+so spans opened in a producer thread (a prefetcher's, a store writer's)
+never cross the consumer's.  Each span records its ``span_id``, the
+``parent_id`` of the span that enclosed it on the same thread (``None``
+at a thread's top) and the ``thread`` that opened it, from which a
+reader computes self time.
+
+Compile work is counted where it happens: the first enabled tracer
+registers one process-wide ``jax.monitoring`` listener, and each jit
+trace, lowering and backend compile adds to the innermost open span of
+every enabled tracer on the calling thread (attrs ``traces``,
+``compiles``, ``cache_loads``, ``jit_s``), or to the tracer's
+:attr:`Tracer.jit_outside` where none of its spans is open there.
+
 This module is stdlib-only at import time (the transport layer, which is
 stdlib-only by contract, hooks into it).
 """
@@ -32,8 +46,11 @@ stdlib-only by contract, hooks into it).
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+import weakref
+from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
 
 
 @dataclasses.dataclass
@@ -48,8 +65,11 @@ class SpanRecord:
     t_sim: Optional[float] = None   # simulated seconds (run clock)
     dur_sim: Optional[float] = None
     clock: str = "wall"             # timeline domain: "wall" | "sim"
-    depth: int = 0                  # nesting depth at entry (LIFO check)
+    depth: int = 0                  # nesting depth on its thread at entry
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    span_id: Optional[int] = None   # unique within its tracer
+    parent_id: Optional[int] = None  # enclosing span on the same thread
+    thread: Optional[str] = None    # name of the thread that opened it
 
     def set(self, **attrs):
         """Attach attributes after entry (e.g. a loss known at exit)."""
@@ -72,20 +92,40 @@ NULL_SPAN = _NullSpan()
 
 
 class _SpanCM:
-    """Context manager closing one live span LIFO."""
+    """Context manager closing one live span on its thread's stack; in
+    profile mode it also holds the span's profiler annotation."""
 
-    __slots__ = ("_tracer", "_rec", "_wall0")
+    __slots__ = ("_tracer", "_rec", "_stack", "_annotation")
 
-    def __init__(self, tracer: "Tracer", rec: SpanRecord, wall0: float):
+    def __init__(self, tracer: "Tracer", rec: SpanRecord,
+                 stack: List[SpanRecord], annotation):
         self._tracer = tracer
         self._rec = rec
-        self._wall0 = wall0
+        self._stack = stack
+        self._annotation = annotation
 
     def __enter__(self) -> SpanRecord:
         return self._rec
 
     def __exit__(self, exc_type, exc, tb):
-        self._tracer._close_span(self._rec, self._wall0)
+        rec, stack = self._rec, self._stack
+        # spans close LIFO by construction (context managers unwind the
+        # stack); tolerate a mismatch rather than corrupt the stack
+        if stack and stack[-1] is rec:
+            stack.pop()
+        else:
+            for i, open_rec in enumerate(stack):
+                if open_rec is rec:
+                    del stack[i]
+                    break
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        tracer = self._tracer
+        rec.dur_wall = tracer._now() - rec.t_wall
+        sim1 = tracer._sim_now()
+        if rec.t_sim is not None and sim1 is not None:
+            rec.dur_sim = sim1 - rec.t_sim
+        tracer._store(rec)
         return False
 
 
@@ -110,7 +150,11 @@ class Tracer:
     :class:`~repro.experiments.runner.Runner` inject it after
     construction.  ``max_events`` bounds memory: past the cap new events
     are counted in :attr:`dropped` instead of stored (never an error —
-    observability must not take the run down).
+    observability must not take the run down).  ``profile=True`` makes
+    every span, on every thread, also hold a
+    ``jax.profiler.TraceAnnotation`` of its name, so that a
+    ``jax.profiler.trace`` shows it in its host planes on the device
+    trace's clock.
     """
 
     def __init__(self, enabled: bool = True, *,
@@ -125,7 +169,21 @@ class Tracer:
         self.t0 = self._wall()
         self.events: List[SpanRecord] = []   # closed, in close order
         self.dropped = 0
-        self._stack: List[SpanRecord] = []   # open spans, LIFO
+        # compile work (traces, compiles, cache_loads, jit_s) that no
+        # span of this tracer held: none was open on its thread
+        self.jit_outside: Dict[str, float] = {}
+        self._ids = itertools.count(1)
+        self._local = threading.local()      # .stack: this thread's open spans
+        self._stacks: Dict[int, List[SpanRecord]] = {}   # every thread's
+        self._annotation = None
+        if self.enabled:
+            if self.profile:
+                try:
+                    from jax.profiler import TraceAnnotation
+                    self._annotation = TraceAnnotation
+                except ImportError:
+                    pass
+            _COMPILE_WATCH.add(self)
 
     # ------------------------------------------------------------------
     def bind_sim_clock(self, fn: Callable[[], float]):
@@ -139,6 +197,14 @@ class Tracer:
     def _sim_now(self) -> Optional[float]:
         return None if self.sim_clock is None else float(self.sim_clock())
 
+    def _thread_stack(self) -> List[SpanRecord]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            self._stacks[threading.get_ident()] = stack
+            return stack
+
     # ------------------------------------------------------------------
     def span(self, name: str, *, track: str = "main", **attrs):
         """Context manager timing one dual-clock span.
@@ -149,40 +215,52 @@ class Tracer:
         """
         if not self.enabled:
             return _NULL_CM
-        wall0 = self._now()
+        stack = self._thread_stack()
         rec = SpanRecord(name=name, track=track, kind="span",
-                         t_wall=wall0, dur_wall=0.0,
+                         t_wall=self._now(), dur_wall=0.0,
                          t_sim=self._sim_now(), clock="wall",
-                         depth=len(self._stack), attrs=dict(attrs))
-        self._stack.append(rec)
-        if self.profile:
-            _enter_profiler_annotation(rec, name)
-        return _SpanCM(self, rec, wall0)
+                         depth=len(stack), attrs=attrs,
+                         span_id=next(self._ids),
+                         parent_id=stack[-1].span_id if stack else None,
+                         thread=threading.current_thread().name)
+        stack.append(rec)
+        annotation = None
+        if self._annotation is not None:
+            annotation = self._annotation(name)
+            annotation.__enter__()
+        return _SpanCM(self, rec, stack, annotation)
 
-    def _close_span(self, rec: SpanRecord, wall0: float):
-        # spans close LIFO by construction (context managers unwind the
-        # stack); tolerate a mismatch rather than corrupt the stack
-        if self._stack and self._stack[-1] is rec:
-            self._stack.pop()
-        elif rec in self._stack:
-            self._stack.remove(rec)
-        if self.profile:
-            _exit_profiler_annotation(rec)
-        rec.dur_wall = self._now() - wall0
-        sim1 = self._sim_now()
-        if rec.t_sim is not None and sim1 is not None:
-            rec.dur_sim = sim1 - rec.t_sim
-        self._store(rec)
+    def iter_span(self, iterable: Iterable, name: str,
+                  track: str = "main") -> Iterator:
+        """Iterate ``iterable`` with each ``next()`` in a span of its own
+        (the last, which finds it exhausted, too): the time a consumer
+        waits on its producer, or a producer takes per item."""
+        if not self.enabled:
+            return iter(iterable)
+        return self._iter_span(iter(iterable), name, track)
+
+    def _iter_span(self, it: Iterator, name: str, track: str) -> Iterator:
+        while True:
+            with self.span(name, track=track):
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
+            yield item
 
     def instant(self, name: str, *, track: str = "main", **attrs):
         """Record a zero-duration event at the current clocks."""
         if not self.enabled:
             return
+        stack = self._thread_stack()
         self._store(SpanRecord(name=name, track=track, kind="instant",
                                t_wall=self._now(), dur_wall=0.0,
                                t_sim=self._sim_now(), dur_sim=0.0,
-                               clock="wall", depth=len(self._stack),
-                               attrs=dict(attrs)))
+                               clock="wall", depth=len(stack),
+                               attrs=attrs, span_id=next(self._ids),
+                               parent_id=(stack[-1].span_id if stack
+                                          else None),
+                               thread=threading.current_thread().name))
 
     def record_span(self, name: str, *, track: str = "main",
                     t_sim: float, dur_sim: float, kind: str = "span",
@@ -198,13 +276,22 @@ class Tracer:
         self._store(SpanRecord(name=name, track=track, kind=kind,
                                t_wall=self._now(), dur_wall=0.0,
                                t_sim=float(t_sim), dur_sim=float(dur_sim),
-                               clock="sim", depth=0, attrs=dict(attrs)))
+                               clock="sim", depth=0, attrs=attrs,
+                               span_id=next(self._ids)))
 
     def _store(self, rec: SpanRecord):
         if len(self.events) >= self.max_events:
             self.dropped += 1
             return
         self.events.append(rec)
+
+    def _add_jit(self, counts: Dict[str, float]):
+        """Add one compile event's counts to the innermost span open on
+        the calling thread, else to :attr:`jit_outside`."""
+        stack = getattr(self._local, "stack", None)
+        target = stack[-1].attrs if stack else self.jit_outside
+        for k, v in counts.items():
+            target[k] = target.get(k, 0) + v
 
     # ------------------------------------------------------------------
     def ingest_fleet_trace(self, trace, *, track: str = "scheduler",
@@ -249,36 +336,100 @@ class Tracer:
         return sorted({e.track for e in self.events})
 
     def summary(self) -> dict:
+        open_spans = sum(len(s) for s in list(self._stacks.values()))
         return {"events": len(self.events), "dropped": self.dropped,
-                "open_spans": len(self._stack), "tracks": self.tracks()}
+                "open_spans": open_spans, "tracks": self.tracks(),
+                "jit_outside": dict(self.jit_outside)}
 
+
+# ---------------------------------------------------------------------------
+# compile counter: one jax.monitoring listener for the process
+# ---------------------------------------------------------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_JIT_EVENTS = (_TRACE_EVENT, _LOWER_EVENT, _COMPILE_EVENT)
+
+
+class _CompileWatch:
+    """Hands each jit trace, lowering and backend compile of the process
+    to every live enabled tracer (:meth:`Tracer._add_jit`).
+
+    JAX reports each of the three at exit with its seconds, and at entry
+    as a scalar (its start time).  Traces nest (a jitted function traces
+    the jitted functions it calls, ``jnp`` ops included), so an event
+    counts only where no event of its kind encloses it on its thread,
+    and its seconds only where no event of any kind does: ``jit_s`` is
+    wall time, never counted twice.  A backend compile that found its
+    executable in the persistent cache (the cache-hit event fires inside
+    it) counts as a ``cache_loads``, else as a ``compiles``.
+    """
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._registered = False
+        self._tracers: tuple = ()      # weakrefs; replaced whole, never mutated
+        self._local = threading.local()   # .open: the thread's open events
+
+    def add(self, tracer: "Tracer"):
+        with self._lock:
+            if not self._registered:
+                try:
+                    from jax import monitoring
+                except ImportError:
+                    return
+                monitoring.register_scalar_listener(self._enter)
+                monitoring.register_event_listener(self._event)
+                monitoring.register_event_duration_secs_listener(self._exit)
+                self._registered = True
+            self._tracers = tuple(r for r in self._tracers
+                                  if r() is not None) + (weakref.ref(tracer),)
+
+    def _open(self) -> list:
+        try:
+            return self._local.open
+        except AttributeError:
+            self._local.open = []
+            self._local.hit = False
+            return self._local.open
+
+    def _enter(self, event, value, **_):
+        if event in _JIT_EVENTS:
+            self._open().append(event)
+
+    def _event(self, event, **_):
+        if event == _CACHE_HIT_EVENT:
+            self._open()
+            self._local.hit = True
+
+    def _exit(self, event, duration, **_):
+        if event not in _JIT_EVENTS:
+            return
+        open_events = self._open()
+        for i in range(len(open_events) - 1, -1, -1):
+            if open_events[i] == event:
+                del open_events[i]
+                break
+        counts: Dict[str, float] = {}
+        if event not in open_events:
+            if event == _TRACE_EVENT:
+                counts["traces"] = 1
+            elif event == _COMPILE_EVENT:
+                counts["cache_loads" if self._local.hit else "compiles"] = 1
+        if event == _COMPILE_EVENT:
+            self._local.hit = False
+        if not open_events:
+            counts["jit_s"] = float(duration)
+        if counts:
+            for ref in self._tracers:
+                tracer = ref()
+                if tracer is not None:
+                    tracer._add_jit(counts)
+
+
+_COMPILE_WATCH = _CompileWatch()
 
 # shared disabled tracer: thread it unconditionally, costs ~nothing
 NULL_TRACER = Tracer(enabled=False)
-
-
-# ---------------------------------------------------------------------------
-# optional jax.profiler coupling (host-side spans only; lazy import so the
-# tracer stays stdlib-only unless profiling is actually requested)
-# ---------------------------------------------------------------------------
-
-_ANNOTATIONS: Dict[int, Any] = {}
-
-
-def _enter_profiler_annotation(rec: SpanRecord, name: str):
-    try:
-        from repro.observability.profiling import trace_annotation
-        cm = trace_annotation(name)
-        cm.__enter__()
-        _ANNOTATIONS[id(rec)] = cm
-    except Exception:
-        pass
-
-
-def _exit_profiler_annotation(rec: SpanRecord):
-    cm = _ANNOTATIONS.pop(id(rec), None)
-    if cm is not None:
-        try:
-            cm.__exit__(None, None, None)
-        except Exception:
-            pass

@@ -65,7 +65,7 @@ class StreamingActivationStore(ActivationStore):
     # ------------------------------------------------------------------
     # producer side (device actors)
     # ------------------------------------------------------------------
-    def start_writer(self):
+    def start_writer(self, tracer=None):
         """No writer thread: the ring IS the async boundary."""
 
     def submit(self, client_id: int, shard: dict, t_arrival: float = 0.0,

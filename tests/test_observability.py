@@ -15,6 +15,8 @@ import json
 import os
 import subprocess
 import sys
+import threading
+import time
 
 import pytest
 
@@ -26,7 +28,7 @@ from repro.observability.export import (read_span_log, to_chrome_trace,
                                         write_span_log)
 from repro.observability.metrics import (MetricsRegistry, format_phase_table,
                                          metric_key, parse_metric_key)
-from repro.observability.tracer import NULL_SPAN, Tracer
+from repro.observability.tracer import NULL_SPAN, NULL_TRACER, Tracer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ARCH = "vit-s"
@@ -81,6 +83,172 @@ def test_sim_clock_binds_once():
     t.bind_sim_clock(lambda: 2.0)       # later binds must not override
     t.instant("x")
     assert t.events[0].t_sim == 1.0
+
+
+def test_spans_nest_per_thread_with_parent_links():
+    """A producer thread's spans nest on a stack of their own: they take
+    no parent from the consumer's open spans, and the consumer's spans
+    keep theirs while the producer runs concurrently."""
+    from repro.data.pipeline import Prefetcher
+
+    t = Tracer()
+
+    def produce():
+        for i in range(4):
+            with t.span("pack", track="feed"):
+                time.sleep(0.002)
+            yield i
+
+    with t.span("epoch", track="main") as epoch:
+        feed = Prefetcher(t.iter_span(produce(), "gather", "feed"))
+        for _ in t.iter_span(feed, "wait", "main"):
+            with t.span("step", track="main"):
+                time.sleep(0.002)
+    feed.thread.join(timeout=30)
+    assert not feed.thread.is_alive()
+
+    by_id = {e.span_id: e for e in t.events}
+    assert len(by_id) == len(t.events)          # ids are unique
+    main = threading.current_thread().name
+    for e in t.events:
+        parent = by_id.get(e.parent_id)
+        if e.name == "epoch":
+            assert parent is None and e.depth == 0
+        elif e.name in ("wait", "step"):
+            assert parent is epoch and e.thread == main and e.depth == 1
+        elif e.name == "gather":
+            assert parent is None and e.thread != main and e.depth == 0
+        else:
+            assert e.name == "pack" and parent.name == "gather"
+            assert e.thread == parent.thread != main and e.depth == 1
+    names = [e.name for e in t.events]
+    assert names.count("wait") == 5 and names.count("gather") == 5
+    assert t.summary()["open_spans"] == 0
+
+
+def test_iter_span_times_each_next_and_nothing_between():
+    clock = [0.0]
+    t = Tracer(wall_clock=lambda: clock[0])
+
+    def produce():
+        for i in range(3):
+            clock[0] += 1.0             # each item takes 1 s to produce
+            yield i
+        clock[0] += 0.5                 # and the end 0.5 s to find
+
+    got = []
+    with t.span("loop") as loop:
+        for i in t.iter_span(produce(), "next", "feed"):
+            got.append(i)
+            clock[0] += 10.0            # the consumer's own time
+    assert got == [0, 1, 2]
+    spans = [e for e in t.events if e.name == "next"]
+    assert [e.dur_wall for e in spans] == [1.0, 1.0, 1.0, 0.5]
+    assert all(e.parent_id == loop.span_id and e.track == "feed"
+               for e in spans)
+    # a disabled tracer hands back the plain iterator, recording nothing
+    it = iter([1, 2])
+    assert NULL_TRACER.iter_span(it, "next") is it
+    assert NULL_TRACER.events == []
+
+
+def test_compile_counter_charges_the_innermost_open_span():
+    """A fresh jit's trace and backend compile land, counted once, on
+    the innermost span open on the calling thread; compile work with no
+    span open lands on the tracer's ``jit_outside``."""
+    import jax
+    import jax.numpy as jnp
+
+    t = Tracer()
+    x = jnp.arange(5.0)                 # made (and compiled) outside spans
+    x.block_until_ready()
+
+    @jax.jit
+    def f(v):                           # traces jnp ops within its trace
+        return jnp.sin(v) * 2.0 + jnp.cos(v)
+
+    with t.span("outer") as outer:
+        with t.span("inner") as inner:
+            f(x).block_until_ready()
+        f(x).block_until_ready()        # cached: no jit work at all
+    assert inner.attrs["traces"] == 1
+    assert inner.attrs.get("compiles", 0) \
+        + inner.attrs.get("cache_loads", 0) == 1
+    assert 0.0 < inner.attrs["jit_s"] < inner.dur_wall
+    assert not {"traces", "compiles", "cache_loads",
+                "jit_s"} & set(outer.attrs)
+
+    before = dict(t.jit_outside)
+    jax.jit(lambda v: v * 3.0)(x).block_until_ready()
+    assert t.jit_outside["traces"] == before.get("traces", 0) + 1
+    assert t.jit_outside["jit_s"] > before.get("jit_s", 0.0)
+    assert t.summary()["jit_outside"] == t.jit_outside
+
+
+def test_profile_annotation_is_held_by_its_span(monkeypatch):
+    """In profile mode each span, on any thread, holds a profiler
+    annotation of its name from entry to exit, nested as the spans."""
+    import jax.profiler
+
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name, threading.current_thread().name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, threading.current_thread().name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    plain = Tracer()
+    with plain.span("unprofiled"):
+        pass
+    assert log == []
+
+    t = Tracer(profile=True)
+    main = threading.current_thread().name
+    with t.span("outer"):
+        with t.span("inner"):
+            assert log == [("enter", "outer", main), ("enter", "inner", main)]
+        def side():
+            with t.span("side"):
+                pass
+
+        worker = threading.Thread(target=side, name="side-thread")
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+    assert log[2] == ("exit", "inner", main)
+    assert log[3:] == [("enter", "side", "side-thread"),
+                       ("exit", "side", "side-thread"),
+                       ("exit", "outer", main)]
+
+
+def test_span_links_survive_both_exports(tmp_path):
+    t = Tracer()
+    with t.span("outer", track="server") as outer:
+        with t.span("inner", track="server") as inner:
+            pass
+    path = str(tmp_path / "spans.jsonl")
+    write_span_log(t, path)
+    back = {e.name: e for e in read_span_log(path, strict=True)}
+    assert back["inner"].parent_id == outer.span_id == back["outer"].span_id
+    assert back["inner"].span_id == inner.span_id
+    assert back["outer"].parent_id is None
+    assert back["inner"].thread == threading.current_thread().name
+    doc = to_chrome_trace(t)
+    args = {e["name"]: e["args"] for e in doc["traceEvents"]
+            if e["ph"] == "X"}
+    assert args["inner"]["parent_id"] == outer.span_id
+    assert "parent_id" not in args["outer"]
+    # a span log written before spans carried links still reads
+    old = read_span_log(os.path.join(REPO, "examples", "traces",
+                                     "chaos_smoke_spans.jsonl"))
+    assert old and all(e.span_id is None and e.parent_id is None
+                       for e in old)
 
 
 # ---------------------------------------------------------------------------
