@@ -753,13 +753,22 @@ class AmpereTrainer:
         runs as a single donated ``lax.scan`` over gathered batch indices
         — per-batch losses land on host once per epoch, never per step.
         Pools beyond ``run.device_pool_budget_mb`` fall back to streaming
-        host batches through the double-buffered :class:`DevicePrefetcher`.
+        host batches, gathered from the store's shards without building
+        the pool, through the double-buffered :class:`DevicePrefetcher`.
         """
-        if self.cuts is not None:
-            return self._run_server_phase_hetero(dev_state, srv_params,
-                                                 store, max_epochs)
-        run = self.run
         tracer = self.obs.tracer
+        # the bytes the store concatenates into whole pools in this call,
+        # on its span: the resident pool's, none for streamed epochs
+        phase_span, concat0 = tracer.current_span(), store.pool_concat_bytes
+
+        def done(state):
+            phase_span.set(pool_concat_bytes=store.pool_concat_bytes - concat0)
+            return state
+
+        if self.cuts is not None:
+            return done(self._run_server_phase_hetero(dev_state, srv_params,
+                                                      store, max_epochs))
+        run = self.run
         srv_state = steps.init_server_state(self.model, run, srv_params)
         srv_state, start_epoch = self.runner.restore("server", srv_state,
                                                      step_name="epoch")
@@ -854,12 +863,12 @@ class AmpereTrainer:
                         "val_loss": val["loss"], "val_acc": val["acc"]},
                 sim_time=epoch_sim)
 
-        return self.runner.run_phase(
+        return done(self.runner.run_phase(
             "server", srv_state,
             ((e, None) for e in range(start_epoch, epochs)),
             body, history_key="server", monitor="val_loss",
             checkpoint_every=run.checkpoint_every, ckpt_offset=10_000,
-            step_name="epoch")
+            step_name="epoch"))
 
     def merged_params(self, dev_state, server_params):
         """Full merged model parameters (device block through the server

@@ -28,6 +28,7 @@ epochs with per-bucket entry points.  Disk shards do not persist tags;
 
 from __future__ import annotations
 
+import functools
 import os
 import queue
 import threading
@@ -56,6 +57,9 @@ class ActivationStore:
         self._writer: Optional[threading.Thread] = None
         self._closed = threading.Event()
         self.bytes_received = 0
+        # bytes concatenated into whole pools by _pool: 0 while training
+        # only streams epochs, which gather from the shards
+        self.pool_concat_bytes = 0
         if directory:
             os.makedirs(directory, exist_ok=True)
 
@@ -187,7 +191,10 @@ class ActivationStore:
         if not shards:
             return {}
         keys = shards[0].keys()
-        return {k: np.concatenate([s[k] for s in shards]) for k in keys}
+        pool = {k: np.concatenate([s[k] for s in shards]) for k in keys}
+        with self._lock:
+            self.pool_concat_bytes += sum(v.nbytes for v in pool.values())
+        return pool
 
     def pool(self, client_id: Optional[int] = None,
              dequantize: bool = False, cut: Optional[int] = None) -> dict:
@@ -238,48 +245,77 @@ class ActivationStore:
             del batch["acts_scale"]
         return batch
 
-    def _one_epoch(self, pool: dict, batch_size: int, dequantize: bool):
-        """One shuffled pass over ``pool`` — the single batching loop both
-        :meth:`batches` and :meth:`streaming_batches` draw from, and the
-        rng contract :meth:`epoch_indices` mirrors (one permutation per
-        epoch, trailing remainder dropped)."""
-        n = len(pool["acts"])
+    def _one_epoch(self, shards: List[dict], batch_size: int,
+                   dequantize: bool):
+        """One shuffled pass over the shard snapshot ``shards`` — the
+        single batching loop both :meth:`batches` and
+        :meth:`streaming_batches` draw from, and the rng contract
+        :meth:`epoch_indices` mirrors (one permutation per epoch,
+        trailing remainder dropped).
+
+        Each batch is gathered straight from the shards it touches: a
+        row of the permutation addresses the shards' concatenation
+        order, the cumulative shard sizes map it to (shard, local row),
+        and one fancy index per shard touched fills a fresh array per
+        key.  Batches are bit-identical to indexing the concatenated
+        pool, which is never built."""
+        starts = np.cumsum([0] + [len(s["acts"]) for s in shards])
+        n = int(starts[-1])
         order = self.rng.permutation(n)
+        # per key: the dtype and row shape np.concatenate would give
+        spec = {k: (functools.reduce(np.promote_types,
+                                     [s[k].dtype for s in shards]),
+                    shards[0][k].shape[1:]) for k in shards[0]}
         for s in range(0, n - batch_size + 1, batch_size):
             idx = order[s:s + batch_size]
-            b = {k: v[idx] for k, v in pool.items()}
+            sid = np.searchsorted(starts, idx, side="right") - 1
+            local = idx - starts[sid]
+            pos = np.argsort(sid, kind="stable")
+            bounds = np.flatnonzero(np.diff(sid[pos])) + 1
+            b = {k: np.empty((batch_size,) + shape, dtype)
+                 for k, (dtype, shape) in spec.items()}
+            for p in np.split(pos, bounds):
+                shard, rows = shards[sid[p[0]]], local[p]
+                for k, v in b.items():
+                    v[p] = shard[k][rows]
             yield self._dequant(b) if dequantize else b
 
     def batches(self, batch_size: int, epochs: int = 1,
                 client_id: Optional[int] = None, dequantize: bool = True):
         """Yield shuffled batches over the (consolidated or per-client)
-        pool for ``epochs`` passes."""
-        pool = self._pool(None if self.consolidated and client_id is None
-                          else client_id)
-        if not pool:
+        pool for ``epochs`` passes, each gathered from one snapshot of
+        the shard list, not from a concatenated pool."""
+        shards = self._shards(None if self.consolidated and client_id is None
+                              else client_id)
+        if not shards:
             return
         for _ in range(epochs):
-            yield from self._one_epoch(pool, batch_size, dequantize)
+            yield from self._one_epoch(shards, batch_size, dequantize)
 
     def streaming_batches(self, batch_size: int, poll: float = 0.01,
                           dequantize: bool = True):
         """Train-while-receiving: yields batches from whatever has arrived
-        so far; completes one final full epoch over the COMPLETE pool
-        after ``finish()`` — shards that landed after the last mid-stream
-        snapshot are guaranteed at least one epoch."""
+        so far, each epoch gathered from a snapshot of the shard list
+        (not a concatenated pool); completes one final full epoch over
+        the COMPLETE pool after ``finish()`` — shards that landed after
+        the last mid-stream snapshot are guaranteed at least one
+        epoch."""
         import time
 
+        def rows(shards):
+            return sum(len(s["acts"]) for s in shards)
+
         while not self._closed.is_set():
-            pool = self._pool()
-            if len(pool.get("acts", ())) >= batch_size:
-                yield from self._one_epoch(pool, batch_size, dequantize)
+            shards = self._shards()
+            if rows(shards) >= batch_size:
+                yield from self._one_epoch(shards, batch_size, dequantize)
             else:
                 time.sleep(poll)
         # finish() joins the writer before setting _closed, so this
         # snapshot is the final pool: one guaranteed full epoch over it.
-        pool = self._pool()
-        if len(pool.get("acts", ())) >= batch_size:
-            yield from self._one_epoch(pool, batch_size, dequantize)
+        shards = self._shards()
+        if rows(shards) >= batch_size:
+            yield from self._one_epoch(shards, batch_size, dequantize)
 
 
 def load_store(directory: str, consolidated: bool = True,

@@ -230,6 +230,13 @@ class Tracer:
             annotation.__enter__()
         return _SpanCM(self, rec, stack, annotation)
 
+    def current_span(self):
+        """The innermost span open on the calling thread, for attributes
+        known only later (a shared null span when none is open or the
+        tracer is disabled)."""
+        stack = getattr(self._local, "stack", None) if self.enabled else None
+        return stack[-1] if stack else NULL_SPAN
+
     def iter_span(self, iterable: Iterable, name: str,
                   track: str = "main") -> Iterator:
         """Iterate ``iterable`` with each ``next()`` in a span of its own

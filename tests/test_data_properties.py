@@ -152,6 +152,108 @@ def test_store_async_writer_and_streaming():
     assert n >= 4
 
 
+def _uneven_store(quantize=False, consolidated=True, seed=11, sizes=None):
+    """A seeded store of uneven shards, single-row ones among them,
+    spread over three clients in interleaved order."""
+    st_ = ActivationStore(consolidated=consolidated, quantize_int8=quantize,
+                          seed=seed)
+    r = np.random.default_rng(5)
+    sizes = sizes or [7, 1, 13, 1, 1, 30, 2, 9, 1, 17, 5, 1, 24]
+    for i, n in enumerate(sizes):
+        st_.add(i % 3, {"acts": r.normal(0, 3, (n, 5, 6)).astype(np.float32),
+                        "labels": r.integers(0, 10, n).astype(np.int32)})
+    return st_
+
+
+def _pool_epoch(pool, rng, batch_size, dequantize):
+    """One epoch drawn as the store once drew it: one permutation of the
+    concatenated pool, batches indexed from it, the remainder dropped."""
+    n = len(pool["acts"])
+    order = rng.permutation(n)
+    for s in range(0, n - batch_size + 1, batch_size):
+        b = {k: v[order[s:s + batch_size]] for k, v in pool.items()}
+        if dequantize and "acts_scale" in b:
+            b["acts"] = b["acts"].astype(np.float32) * b.pop("acts_scale")
+        yield b
+
+
+def _assert_same_batches(got, want):
+    assert len(got) == len(want) > 0
+    for g, w in zip(got, want):
+        assert g.keys() == w.keys()
+        for k in g:
+            assert g[k].dtype == w[k].dtype and g[k].shape == w[k].shape
+            np.testing.assert_array_equal(g[k], w[k])
+
+
+@pytest.mark.parametrize("quantize,dequantize,client_id,epochs", [
+    (False, True, None, 3),         # float32, consolidated
+    (True, True, None, 2),          # int8 + acts_scale, dequantized
+    (True, False, None, 2),         # int8 + acts_scale, as stored
+    (False, True, 1, 2),            # per-client mode
+    (True, False, 2, 2),            # per-client, int8 as stored
+], ids=["f32", "int8-dequant", "int8-raw", "per-client", "per-client-int8"])
+def test_store_batches_match_concatenated_pool(quantize, dequantize,
+                                               client_id, epochs):
+    """Batches gathered from the shards are bit-identical, in order,
+    membership, dtype and bytes, to the same seed's batches from the
+    concatenated pool."""
+    consolidated = client_id is None
+    st_ = _uneven_store(quantize, consolidated)
+    pool = _uneven_store(quantize, consolidated).pool(client_id)
+    rng = np.random.default_rng(11)
+    for bs in (4, 9):
+        got = list(st_.batches(bs, epochs=epochs, client_id=client_id,
+                               dequantize=dequantize))
+        want = [b for _ in range(epochs)
+                for b in _pool_epoch(pool, rng, bs, dequantize)]
+        _assert_same_batches(got, want)
+
+
+def test_streaming_batches_gather_late_shards_bit_identical():
+    """``streaming_batches`` draws an epoch over each snapshot, then one
+    over the complete pool after ``finish()``, late single-row shards
+    included, each as the concatenated snapshot would give it."""
+    st_ = _uneven_store(sizes=[7, 1, 13])
+    gen = st_.streaming_batches(4)
+    early = [next(gen) for _ in range(5)]            # 21 rows: one epoch
+    st_.add(1, {"acts": np.full((1, 5, 6), 7, np.float32),
+                "labels": np.full((1,), 77, np.int32)})
+    st_.add(2, {"acts": np.full((3, 5, 6), 8, np.float32),
+                "labels": np.full((3,), 88, np.int32)})
+    st_.finish()
+    rest = list(gen)
+    rng = np.random.default_rng(11)
+    first = _uneven_store(sizes=[7, 1, 13]).pool()
+    _assert_same_batches(early, list(_pool_epoch(first, rng, 4, True)))
+    _assert_same_batches(rest, list(_pool_epoch(st_.pool(), rng, 4, True)))
+    lab = np.concatenate([b["labels"] for b in rest])
+    assert (lab == 77).sum() + (lab == 88).sum() >= 3
+
+
+@pytest.mark.parametrize("draw", ["batches", "streaming_batches"])
+def test_streamed_epoch_never_builds_the_pool(draw, monkeypatch):
+    st_ = _uneven_store()
+    st_.finish()
+
+    def no_pool(*a, **k):
+        raise AssertionError("a streamed epoch concatenated the pool")
+
+    monkeypatch.setattr(st_, "_pool", no_pool)
+    got = list(getattr(st_, draw)(8))
+    assert len(got) == st_.num_samples() // 8
+    assert st_.pool_concat_bytes == 0
+
+
+def test_pool_concat_bytes_counts_whole_pools():
+    st_ = _uneven_store(quantize=True)
+    assert st_.pool_concat_bytes == 0
+    st_.pool()
+    assert st_.pool_concat_bytes == st_.pool_nbytes()
+    st_.pool(dequantize=True)
+    assert st_.pool_concat_bytes == 2 * st_.pool_nbytes()
+
+
 def test_lm_dataset_domain_structure():
     ds = make_lm_dataset(64, seq_len=32, vocab=53, num_domains=4, seed=0)
     assert ds.arrays["tokens"].shape == (64, 32)

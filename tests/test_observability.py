@@ -70,6 +70,23 @@ def test_disabled_tracer_records_nothing_and_yields_null_span():
     assert t.events == [] and t.summary()["events"] == 0
 
 
+def test_current_span_is_the_innermost_open_on_this_thread():
+    t = Tracer()
+    assert t.current_span() is NULL_SPAN
+    with t.span("outer", track="server") as outer:
+        with t.span("inner", track="server"):
+            pass
+        assert t.current_span() is outer
+        seen = []
+        th = threading.Thread(target=lambda: seen.append(t.current_span()))
+        th.start()
+        th.join()
+        assert seen == [NULL_SPAN]      # another thread's stack is empty
+        t.current_span().set(late=1)
+    assert t.events[-1].attrs == {"late": 1}
+    assert Tracer(enabled=False).current_span() is NULL_SPAN
+
+
 def test_event_cap_drops_and_counts_instead_of_erroring():
     t = Tracer(max_events=2)
     for i in range(5):

@@ -252,6 +252,35 @@ def test_run_server_phase_streaming_fallback_budget():
     assert np.isfinite(tr.history["server"][-1]["loss"])
 
 
+@pytest.mark.parametrize("path", ["streamed", "resident"])
+def test_run_server_phase_pool_concat_bytes(path, monkeypatch):
+    """A streamed phase gathers its batches from the shards and never
+    concatenates the pool; a resident one concatenates it once, to
+    upload it.  ``server.phase`` carries the count."""
+    from repro.observability import Observability
+
+    m, run, clients, test = _setup("vit-s")
+    if path == "streamed":
+        run = replace(run, device_pool_budget_mb=0)
+    obs = Observability()
+    tr = AmpereTrainer(m, run, clients, test, patience=50, obs=obs)
+    dev, srv, aux = tr._init_states(jax.random.PRNGKey(0))
+    dev_state = {"device": dev, "aux": aux}
+    store = ActivationStore(seed=0)
+    tr.generate_activations(dev_state, store)
+    if path == "streamed":
+        def no_pool(*a, **k):
+            raise AssertionError("a streamed epoch concatenated the pool")
+        monkeypatch.setattr(store, "_pool", no_pool)
+    tr.run_server_phase(dev_state, srv, store, max_epochs=2)
+    assert len(tr.history["server"]) == 2
+    want = 0 if path == "streamed" else store.pool_nbytes()
+    assert store.pool_concat_bytes == want
+    phase = [e for e in obs.tracer.events if e.name == "server.phase"]
+    assert len(phase) == 1
+    assert phase[0].attrs["pool_concat_bytes"] == want
+
+
 # ---------------------------------------------------------------------------
 # feeding pipeline
 # ---------------------------------------------------------------------------
