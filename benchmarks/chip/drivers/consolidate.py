@@ -43,7 +43,10 @@ def _call(h):
 
 
 def setup(h):
+    from harness.build import sample_keys
+
     h.per_call = sum(len(c) for c in h.b.clients)
+    h.inp_key, h.lab_key = sample_keys(h.b.model)
 
 
 def prime(h):
@@ -85,8 +88,8 @@ def _read_back(h):
         want = len(clients[k])
         if got != want:
             counts_bad += 1
-        elif not np.array_equal(shard["labels"],
-                                clients[k].dataset.arrays["labels"]):
+        elif not np.array_equal(shard[h.lab_key],
+                                clients[k].dataset.arrays[h.lab_key]):
             labels_bad += 1
         mine = picks[(picks >= offsets[k]) & (picks < offsets[k + 1])]
         for r in mine:
@@ -111,9 +114,9 @@ def follow(h, mode="f32", fault=None):
 
     ref, m, split = h.ref, h.model_dict, h.cfg["split"]
     dev, _, _ = ref.init(h.b.key, m, split)
-    images = pool_inputs(h.b.clients, "images")
+    inputs = pool_inputs(h.b.clients, h.inp_key)
     acts = np.asarray(ref.device_forward(
-        dev, jnp.asarray(images[np.asarray(h.rows)]), m, mode), np.float32)
+        dev, jnp.asarray(inputs[np.asarray(h.rows)]), m, mode), np.float32)
     out = {"acts": list(acts), "clients_short": 0, "labels_wrong": 0}
     if fault == "altered":
         out["acts"][0] = out["acts"][0] * 1.01

@@ -157,18 +157,19 @@ def follow(h, mode="f32", fault=None):
     losses and per-leaf norms in the form of ``h.prog``."""
     import jax.numpy as jnp
 
-    from harness.build import pool_inputs
+    from harness.build import pool_inputs, sample_keys
     from harness.plain import device_rounds
 
     ref, m, split = h.ref, h.model_dict, h.cfg["split"]
     dev, _, aux = ref.init(h.b.key, m, split)
-    images = pool_inputs(h.b.clients, "images")
-    labels = pool_inputs(h.b.clients, "labels")
+    inp_key, lab_key = sample_keys(h.b.model)
+    inputs = pool_inputs(h.b.clients, inp_key)
+    labels = pool_inputs(h.b.clients, lab_key)
     rounds = []
     for idx, w in h.rounds:
         # a row past the pool is a cohort fault already; follow a real one
         idx = np.clip(idx, 0, len(labels) - 1)
-        clients = [[(jnp.asarray(images[idx[k, s]]),
+        clients = [[(jnp.asarray(inputs[idx[k, s]]),
                      jnp.asarray(labels[idx[k, s]]))
                     for s in range(idx.shape[1])] for k in range(len(idx))]
         rounds.append((clients, w))

@@ -38,9 +38,11 @@ CHECK_STEPS = 3
 
 
 def setup(h):
+    from harness.build import sample_keys
     from repro.data.activation_store import ActivationStore
 
     run = h.b.spec.run
+    h.inp_key, h.lab_key = sample_keys(h.b.model)
     h.store = ActivationStore(
         directory=None, consolidated=True,
         quantize_int8=run.split.quantize_activations, seed=run.seed)
@@ -61,7 +63,7 @@ def _tap(h, steps, epochs):
     def step(state, batch):
         state, m = step0(state, batch)
         if len(steps) < CHECK_STEPS:
-            steps.append((state["server"], m["loss"], batch["labels"],
+            steps.append((state["server"], m["loss"], batch[h.lab_key],
                           batch["acts"]))
         return state, m
 
@@ -97,6 +99,7 @@ def prime(h):
     import jax
     import jax.numpy as jnp
 
+    from harness.build import pool_inputs
     from harness.compare import diff_norms
     from harness.plain import lr_at
 
@@ -117,12 +120,11 @@ def prime(h):
         tr._server_step, tr._server_epoch = step0, epoch0
     h.epoch_s = time.perf_counter() - t0
     h.srv_params = state["server"]
-    pool_labels = np.concatenate(
-        [c.dataset.arrays["labels"] for c in b.clients])
+    pool_labels = pool_inputs(b.clients, h.lab_key)
     if epochs:
         start, pool, idx, losses = epochs.pop()
         h.rows = list(idx[:CHECK_STEPS])
-        labels = np.asarray(pool["labels"])[idx[:CHECK_STEPS]]
+        labels = np.asarray(pool[h.lab_key])[idx[:CHECK_STEPS]]
         feed = np.asarray(pool["acts"][jnp.asarray(idx[:CHECK_STEPS])])
         after = _scanned(epoch0, start, pool, idx)
         losses = np.asarray(losses)[:CHECK_STEPS]
@@ -191,9 +193,9 @@ def follow(h, mode="f32", fault=None):
 
     ref, m, split = h.ref, h.model_dict, h.cfg["split"]
     dev, srv, _ = ref.init(h.b.key, m, split)
-    images = pool_inputs(h.b.clients, "images")
-    labels = pool_inputs(h.b.clients, "labels")
-    batches = [(ref.device_forward(dev, jnp.asarray(images[r]), m, mode),
+    inputs = pool_inputs(h.b.clients, h.inp_key)
+    labels = pool_inputs(h.b.clients, h.lab_key)
+    batches = [(ref.device_forward(dev, jnp.asarray(inputs[r]), m, mode),
                 jnp.asarray(labels[r])) for r in h.rows]
     half = fault == "half_batch"
     out = server_steps(lambda p, a, y: ref.server_loss(p, a, y, m, mode,

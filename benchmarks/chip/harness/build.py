@@ -34,6 +34,7 @@ def make_spec(cfg, cell, seed, smoke=False):
                     device_pool_budget_mb=cell["device_pool_budget_mb"])
     data = DataSpec(train_samples=cell["data"]["train_samples"],
                     eval_samples=cell["data"]["eval_samples"],
+                    seq_len=cell["data"].get("seq_len", 0),
                     train_seed=derive_seed(seed, "train"),
                     eval_seed=derive_seed(seed, "eval"),
                     partition_seed=derive_seed(seed, "partition"))
@@ -43,12 +44,30 @@ def make_spec(cfg, cell, seed, smoke=False):
 
 
 def check_model(cfg, model):
-    """The configuration file holds the configuration as it is run."""
-    for k, v in cfg["model"].items():
-        got = getattr(model.cfg, k)
-        if (tuple(v) if isinstance(v, list) else v) != got:
-            raise ValueError(f"{cfg['name']}: the model's {k} is {got!r}, "
-                             f"the configuration file says {v!r}")
+    """The configuration file holds the configuration as it is run: each
+    key of its ``model`` block equals the model's field; an object is a
+    dataclass sub-config, compared field by field (``moe.num_experts``)."""
+    def check(want, got, path):
+        for k, v in want.items():
+            key = f"{path}{k}"
+            if not hasattr(got, k):
+                raise ValueError(f"{cfg['name']}: the model has no {key}")
+            have = getattr(got, k)
+            if isinstance(v, dict):
+                check(v, have, key + ".")
+            elif (tuple(v) if isinstance(v, list) else v) != have:
+                raise ValueError(f"{cfg['name']}: the model's {key} is "
+                                 f"{have!r}, the configuration file says "
+                                 f"{v!r}")
+
+    check(cfg["model"], model.cfg, "")
+
+
+def sample_keys(model):
+    """(input key, target key) of a model's samples, as the program keys
+    them: a token model reads and predicts its tokens."""
+    return ("tokens", "tokens") if model.kind == "lm" \
+        else ("images", "labels")
 
 
 def build(cfg, cell, seed, obs, smoke=False):

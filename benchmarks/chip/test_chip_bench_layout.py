@@ -46,14 +46,63 @@ def test_top_level_keys_and_limits():
 
 
 def test_configs_files_and_reduced():
+    reg = _load_registry()
     for c in SPEC["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert c["file"] == f"benchmarks/chip/configs/{c['name']}.json"
         data = json.loads((ROOT / c["file"]).read_text())
         assert data["name"] == c["name"]
-        assert data["reduced"] == c["reduced"] == []
+        reg.check_config(c, data)
         assert (BENCH / "configs" / f"{c['name']}.py").is_file()
         assert any(w["config"] == c["name"] for w in SPEC["workloads"])
+
+
+# a cut in the form the model-configs guide asks for: 8 of 64 experts
+# and an eighth of the vocabulary held, the file stating both
+CUT = {"name": "cut", "reduced": ["moe.num_experts", "vocab_size"],
+       "model": {"vocab_size": 20480, "d_model": 2048,
+                 "moe": {"num_experts": 8, "top_k": 6}},
+       "published": {"moe.num_experts": 64, "vocab_size": 163840},
+       "deployment": "8 chips share every layer: 8 experts and 1/8 of the "
+                     "vocabulary each"}
+
+
+def _cut(**change):
+    data = json.loads(json.dumps(CUT))
+    data.update(change)
+    return {"name": "cut", "reduced": data["reduced"]}, data
+
+
+@pytest.mark.parametrize("form", ["model_block", "catalog_keys"])
+def test_check_config_takes_a_documented_cut(form):
+    """A nested key of the ``model`` block, or a catalog key at the file's
+    top level, as the catalog's config holds it."""
+    change = {} if form == "model_block" else {
+        "reduced": ["num_hidden_layers"], "num_hidden_layers": 5,
+        "published": {"num_hidden_layers": 27}}
+    _load_registry().check_config(*_cut(**change))
+
+
+@pytest.mark.parametrize("fault,change,says", [
+    ("undocumented", {"published": {"vocab_size": 163840}},
+     "gives no value"),
+    ("published_as_run", {"published": {"moe.num_experts": 8,
+                                        "vocab_size": 163840}},
+     "not a cut"),
+    ("not_in_model", {"reduced": ["moe.num_layers"],
+                      "published": {"moe.num_layers": 27}},
+     "not in the file"),
+    ("no_deployment", {"deployment": ""}, "deployment"),
+    ("width", {"reduced": ["moe.top_k"], "published": {"moe.top_k": 8}},
+     "a width"),
+    ("entry_differs", {}, "BENCHMARK.json reduces"),
+])
+def test_check_config_refuses_an_undocumented_cut(fault, change, says):
+    entry, data = _cut(**change)
+    if fault == "entry_differs":
+        entry["reduced"] = ["vocab_size"]
+    with pytest.raises(ValueError, match=says):
+        _load_registry().check_config(entry, data)
 
 
 @pytest.mark.parametrize("cell", CELLS)
